@@ -101,9 +101,13 @@ def _update_perf_summary(suite: str, records: list[dict], seconds: float,
 
 
 def main() -> None:
+    from pathlib import Path
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     from . import (
         fig14_pipelining,
-        fusion,
         perf_baseline,
         fig15_parallel,
         selectivity,
@@ -127,7 +131,6 @@ def main() -> None:
         "perf": perf_baseline.run,
         "throughput": throughput.run,
         "selectivity": selectivity.run,
-        "fusion": fusion.run,
         "snapshot": snapshot_restore.run,
     }
     from .common import RECORDS

@@ -22,13 +22,7 @@ from ..data import synth_graph as SG
 from .base import ArchConfig, Cell
 
 # PubMed-M full-scale statistics (paper Table 1)
-FULL = dict(
-    n_docs=23_326_299,
-    n_terms=27_883,
-    n_authors=6_301_521,
-    dt_edges=901_388_401,
-    da_edges=61_329_130,
-)
+FULL = SG.PUBMED_TABLE1
 
 EDGE_AXES = ("data", "model")
 

@@ -196,6 +196,8 @@ class PreparedQuery:
             lines.append(f"ops: {sig}")
             for g in fusion_groups(self.phys):
                 lines.append(f"  fused region: {g}")
+        for label, route in self.hop_routes(batched=self.batched_fn is not None):
+            lines.append(f"  hop {label}: {route}")
         for h in self.hop_estimates or []:
             lines.append(
                 f"  hop I_{h['table']}.{h['src_key']}: "
@@ -204,6 +206,46 @@ class PreparedQuery:
         if analyze:
             lines.append(self.profile(**params).render())
         return "\n".join(lines)
+
+    def hop_routes(self, batched: bool = False) -> list[tuple[str, str]]:
+        """Where each hop of the plan (mask sub-programs included) runs:
+        ``pallas`` — the hop kernel over the destination-sorted edges of the
+        reverse index — ``pallas (source-sorted)`` — the same kernel over the
+        hop's own index, where the database holds no reverse index — or
+        ``xla: <reason>`` for hops that stay on XLA by design. The ladder can
+        still demote a Pallas hop at run time; this is the plan's route."""
+        from .lower import (
+            HopOp, LBin, LCall, LParam, LSeedScalar, SeedOp, iter_flat_ops,
+        )
+
+        def batch_dependent(e) -> bool:
+            if isinstance(e, (LParam, LSeedScalar)):
+                return True
+            if isinstance(e, LBin):
+                return batch_dependent(e.left) or batch_dependent(e.right)
+            if isinstance(e, LCall):
+                return any(batch_dependent(a) for a in e.args)
+            return False
+
+        def walk(phys):
+            for op in iter_flat_ops(phys):
+                if isinstance(op, SeedOp):
+                    for prog in op.programs:
+                        yield from walk(prog)
+                if not isinstance(op, HopOp):
+                    continue
+                label = f"{op.table}.{op.src_key}->{op.dst_entity}"
+                if self.strategy != "frontier":
+                    route = f"xla: {self.strategy} strategy"
+                elif batched and op.measure is not None and batch_dependent(op.measure):
+                    route = "xla: per-row measure (no shared edge stream)"
+                elif op.pull is None:
+                    route = "pallas (source-sorted)"
+                else:
+                    route = "pallas"
+                yield label, route
+
+        return list(walk(self.phys)) if self.phys is not None else []
 
     def _batch_args(self, param_arrays: dict) -> tuple[list[np.ndarray], int]:
         """Validate one [B] array (or Python list) per parameter: every
@@ -316,12 +358,15 @@ class GQFastEngine:
         self._cache: PreparedCache = PreparedCache(max_prepared)
         # per-plan-signature observed active fractions (fed by profile runs)
         self.calibration = CalibrationStore()
+        # the mesh's edge-sharded placement, shared by every prepared query
+        self._sharded_db = None
 
     def invalidate_prepared(self) -> int:
         """Drop every cached prepared query. Required after the device arrays
         under the executables change in place — a scrubber heal or a snapshot
         generation swap — because traced executables close over the old
         buffers. Returns the number of entries dropped."""
+        self._sharded_db = None
         return self._cache.clear()
 
     def prepare(self, sql: str, block_skipping: str = "auto",
@@ -379,7 +424,11 @@ class GQFastEngine:
             with T.span("compile") as csp:
                 if self.mesh is not None:
                     strategy = "distributed"  # skipping n/a: sharded XLA hops
-                    sdb = X.shard_edges(self.db.device, self.mesh, self.shard_axes)
+                    if self._sharded_db is None:
+                        self._sharded_db = X.shard_edges(
+                            self.db.device, self.mesh, self.shard_axes
+                        )
+                    sdb = self._sharded_db
                     fn = X.compile_frontier_distributed(
                         self.db.device, phys, self.mesh, self.shard_axes,
                         sharded_db=sdb,
@@ -395,7 +444,7 @@ class GQFastEngine:
                         strategy = self._pick_strategy(plan, plan_sig)
                     if strategy == "frontier" and fusion != "off":
                         with T.span("fuse"):
-                            phys = fuse_plan(phys, fusion)
+                            phys = fuse_plan(phys)
                     if strategy == "frontier":
                         fn = X.compile_frontier(
                             self.db.device, phys,

@@ -6,14 +6,14 @@ Every strategy is a *thin interpreter* over the lowered physical IR built by
 primitive each op maps to:
 
   * ``frontier`` — bottom-up fully pipelined execution, TPU-native: each HopOp
-    dispatches through :func:`repro.kernels.ops.fragment_spmv` (Pallas on TPU,
-    interpret/XLA fallback on CPU) over dense per-entity-domain vectors, or —
-    when the index's columns are stored bit-packed by the device column store
-    (:mod:`repro.storage`) — through the decode-fused
-    :func:`repro.kernels.ops.fragment_spmv_packed`, which unpacks dst ids and
-    measures block-at-a-time in VMEM (the paper's compression-inside-the-
-    operator design). JAX tracing fuses the whole plan into one XLA
-    executable; intermediates are vectors, never materialized join tables.
+    runs the Pallas hop kernel (:mod:`repro.kernels.fragment_spmv`; compiled
+    on TPU, interpret mode on CPU) over dense per-entity-domain vectors and
+    the hop's destination-sorted edges (``HopOp.pull``: the index keyed on
+    the destination). Bit-packed columns of the device column store
+    (:mod:`repro.storage`) decode block-at-a-time in VMEM inside the kernel
+    (the paper's compression-inside-the-operator design). JAX tracing fuses
+    the whole plan into one XLA executable; intermediates are vectors, never
+    materialized join tables.
   * ``fragment_loop`` — paper-faithful port of the generated C++ (Fig. 3):
     nested ``lax.fori_loop``s walk one fragment at a time, scalar accumulator
     updates. The §Perf baseline demonstrating why the vectorized rewrite is
@@ -265,10 +265,7 @@ def densify_plan(phys: PhysicalPlan) -> PhysicalPlan:
 def _trace_clean() -> bool:
     """True outside any jax trace — the guard that keeps span recording and
     ``block_until_ready`` fencing strictly on the host side of jit."""
-    try:
-        return jax.core.trace_state_clean()
-    except AttributeError:  # pragma: no cover - very old jax
-        return True
+    return jax.core.trace_ctx.is_top_level()
 
 
 def walk_ir(phys: PhysicalPlan, interp: "_Interp", stop: int | None = None):
@@ -485,15 +482,20 @@ class _FrontierInterp(_Interp):
 
     Frontier sparsity (DESIGN.md §Sparsity): every hop first short-circuits an
     all-zero frontier inside the trace (``lax.cond`` on the support count — a
-    died-early chain stops paying per-hop scan cost), then passes the index's
-    per-block src-range metadata to the kernel dispatch so blocks the support
-    cannot reach are never streamed. ``block_skipping`` ('auto' | 'on' |
-    'off') is threaded through from prepare time."""
+    died-early chain stops paying per-hop scan cost). Over the
+    destination-sorted edges the kernel then gathers only active frontier
+    chunks but streams every edge block; a hop without a reverse index runs
+    over its source-sorted edges, where the per-block src-range metadata
+    keeps unreachable blocks from being streamed. ``block_skipping`` ('auto'
+    | 'on' | 'off') is threaded through from prepare time."""
 
     # Subclasses whose hops run collectives (the edge-sharded distributed
     # interp) must not branch per-hop: lax.cond with a psum inside one branch
     # deadlocks when shards disagree on the frontier. They opt out here.
     early_exit = True
+    # Hops run the Pallas kernel over the destination-sorted edges; the
+    # edge-sharded interp reduces shard-local XLA segments instead.
+    kernel_hops = True
     # The edge-sharded interp also opts out of the single-pass fused-region
     # kernel (its hops are shard-local segment reduces, no VMEM pipeline) and
     # replays fused regions op-by-op via the generic rule instead.
@@ -559,9 +561,9 @@ class _FrontierInterp(_Interp):
         ))
 
     def _hop_body(self, w, op: HopOp):
-        fused = self.spmv_fused(w, op)
-        if fused is not None:
-            return fused
+        pulled = self.pull_hop(w, op)
+        if pulled is not None:
+            return pulled
         src, dst, valid = self.edge_arrays(op)
         E = src.shape[0]
         if op.measure is not None and self.use_measures:
@@ -574,59 +576,61 @@ class _FrontierInterp(_Interp):
     def edge_arrays(self, op: HopOp):
         return op.src_ids, op.dst_ids, None
 
-    def _packed_layout(self, op: HopOp):
-        """Classify the hop's physical layout for the decode-fused kernels:
-        returns None when there is nothing packed to fuse (all-dense hop),
-        else ``(dst_packed, m_mode, m_operand, m_width, mdict)``. A dense
-        ``m_mode`` leaves ``m_operand`` None — the caller evaluates the
-        measure expression and broadcasts it to its own frontier shape.
-        Single classification shared by the SpMV and SpMM fused paths so the
-        mode dispatch cannot drift between them."""
-        dst_packed = isinstance(op.dst_col, PackedColumn)
-        m = op.measure if self.use_measures else None
+    def _measure_layout(self, measure):
+        """``(m_mode, m_operand, m_width, mdict)`` for the kernels: a single
+        packed column streams its words ('packed' | 'dict'); any other
+        measure is evaluated and broadcast by the caller ('dense', operand
+        None)."""
+        m = measure if self.use_measures else None
         if m is None:
-            m_mode, m_operand, m_width, mdict = "none", None, 0, None
-        elif isinstance(m, LCol) and isinstance(m.col, PackedColumn):
-            m_mode, m_operand, m_width, mdict = "packed", m.col.words, m.col.width, None
-        elif isinstance(m, LCol) and isinstance(m.col, DictPackedColumn):
-            m_mode, m_operand, m_width, mdict = (
-                "dict", m.col.words, m.col.width, m.col.dictionary,
-            )
-        else:
-            m_mode, m_operand, m_width, mdict = "dense", None, 0, None
-        if not (dst_packed or m_mode in ("packed", "dict")):
-            return None
-        return dst_packed, m_mode, m_operand, m_width, mdict
+            return "none", None, 0, None
+        if isinstance(m, LCol) and isinstance(m.col, PackedColumn):
+            return "packed", m.col.words, m.col.width, None
+        if isinstance(m, LCol) and isinstance(m.col, DictPackedColumn):
+            return "dict", m.col.words, m.col.width, m.col.dictionary
+        return "dense", None, 0, None
 
-    def spmv_fused(self, w, op: HopOp):
-        """Decode-fused hop: stream packed columns straight into the kernel
-        (the paper's compression-inside-the-operator design). Engaged when the
-        dst column is bit-packed and/or the measure is a single packed column;
-        returns None when there is nothing to fuse (all-dense hop) and the
-        plain kernel path runs instead."""
+    def _pull_operands(self, op: HopOp):
+        """The hop's destination-sorted streams (``op.pull``) as keyword
+        arguments of the packed hop entries — packed source ids and a single
+        packed measure column decode inside the kernel — or None when the
+        hop takes the source-sorted XLA path instead: the ``xla`` rung, no
+        reverse index, or a batch-dependent measure (no shared edge stream;
+        XLA by design)."""
+        p = op.pull
+        if p is None or not self.use_pallas or not self.kernel_hops:
+            return None
+        m_mode, m_operand, m_width, mdict = self._measure_layout(p.measure)
+        E = p.dst_ids.shape[0]
+        if m_mode == "dense":
+            mv = jnp.asarray(
+                eval_lexpr(p.measure, self.params, self.scalars, self.col),
+                jnp.float32,
+            )
+            if mv.ndim >= 2:
+                return None
+            m_operand = jnp.broadcast_to(mv, (E,))
+        src_packed = isinstance(p.src_col, PackedColumn)
+        return dict(
+            src_ids=p.src_col.words if src_packed else p.src_col.materialize(),
+            dst=p.dst_ids, measure=m_operand, mdict=mdict, n_dst=op.dom_dst,
+            src_width=p.src_col.width if src_packed else 0,
+            m_mode=m_mode, m_width=m_width,
+        )
+
+    def pull_hop(self, w, op: HopOp):
+        """The hop kernel over the destination-sorted edges. Skipping there is
+        by frontier chunk: only 128-entry chunks of ``w`` holding a
+        non-identity value are gathered (``block_skipping`` 'off' reads every
+        chunk)."""
         from ..kernels import ops as K
 
-        layout = self._packed_layout(op)
-        if layout is None:
+        kw = self._pull_operands(op)
+        if kw is None:
             return None
-        dst_packed, m_mode, m_operand, m_width, mdict = layout
-        if m_mode == "dense":
-            # complex measure expression over a packed index: evaluate it
-            # (decoding any packed LCols it references) and stream it dense;
-            # dst still decodes in VMEM
-            mv = eval_lexpr(op.measure, self.params, self.scalars, self.col)
-            m_operand = jnp.broadcast_to(
-                jnp.asarray(mv, jnp.float32), (op.src_ids.shape[0],)
-            )
         return K.fragment_spmv_packed(
-            w, op.src_ids,
-            op.dst_col.words if dst_packed else op.dst_col.materialize(),
-            m_operand, mdict,
-            n_dst=op.dom_dst,
-            dst_width=op.dst_col.width if dst_packed else 0,
-            m_mode=m_mode, m_width=m_width, op=self.sr.name,
-            use_pallas=self.use_pallas,
-            blocks=self.blocks_for(op), block_skipping=self.block_skipping,
+            w, op=self.sr.name, use_pallas=True,
+            block_skipping=self.block_skipping, **kw,
         )
 
     def spmv(self, w, src, dst, m, valid, op: HopOp):
@@ -640,39 +644,14 @@ class _FrontierInterp(_Interp):
 
     # -- pipelined fused regions (DESIGN.md §Pipelined fusion) --------------
 
-    def _hop_operands(self, op: HopOp, reach=None):
+    def _hop_operands(self, op: HopOp):
         """One HopOp → the fused entry's :class:`FusedHopOperands` bundle, or
-        None when the hop has a shape the single-pass kernel cannot express
-        (batch-dependent measure expression) — the caller then replays the
-        region unfused."""
+        None when the hop does not run the kernel (see ``_pull_operands``) —
+        the caller then replays the region unfused."""
         from ..kernels import ops as K
 
-        layout = self._packed_layout(op)
-        if layout is None:
-            dst_packed, m_operand, m_width, mdict = False, None, 0, None
-            m_mode = (
-                "dense"
-                if op.measure is not None and self.use_measures else "none"
-            )
-        else:
-            dst_packed, m_mode, m_operand, m_width, mdict = layout
-        if m_mode == "dense" and m_operand is None:
-            mv = jnp.asarray(
-                eval_lexpr(op.measure, self.params, self.scalars, self.col),
-                jnp.float32,
-            )
-            if mv.ndim >= 2:  # batch-dependent measure: no shared edge stream
-                return None
-            m_operand = jnp.broadcast_to(mv, (op.src_ids.shape[0],))
-        return K.FusedHopOperands(
-            src_ids=op.src_ids,
-            dst=op.dst_col.words if dst_packed else op.dst_col.materialize(),
-            measure=m_operand, mdict=mdict,
-            n_dst=op.dom_dst,
-            dst_width=op.dst_col.width if dst_packed else 0,
-            m_mode=m_mode, m_width=m_width,
-            blocks=self.blocks_for(op), reach=reach,
-        )
+        kw = self._pull_operands(op)
+        return K.FusedHopOperands(**kw) if kw is not None else None
 
     def _fused_region_args(self, op: FusedHopOp):
         """Collect the region's kernel arguments: the two hop bundles, the
@@ -687,7 +666,7 @@ class _FrontierInterp(_Interp):
             return None
         hop2 = None
         if h2_op is not None:
-            hop2 = self._hop_operands(h2_op, reach=op.reach)
+            hop2 = self._hop_operands(h2_op)
             if hop2 is None:
                 return None
         mid_mask = None
@@ -765,6 +744,40 @@ class _FrontierInterp(_Interp):
         return cont(state)
 
 
+class hoisted_jit:
+    """``jax.jit(fn)`` with every array ``fn`` closes over — the plan's edge
+    columns, masks and degree vectors — passed to the executable as an
+    argument. Plain ``jax.jit`` bakes closed-over arrays into the executable
+    as constants: at deployment size that is hundreds of MB per executable,
+    copied into host memory and through the compiler for every prepared
+    shape. Traces once per argument signature; ``lower`` mirrors
+    ``jax.jit(...).lower`` for executable inspection."""
+
+    def __init__(self, fn):
+        self.fn = fn
+        self._entries: dict = {}
+
+    def _entry(self, args):
+        key = tuple(jax.typeof(a) for a in args)
+        if key not in self._entries:
+            closed = jax.make_jaxpr(self.fn)(*args)
+            consts = [jnp.asarray(c) if isinstance(c, np.ndarray) else c
+                      for c in closed.consts]
+            run = jax.jit(
+                lambda consts, *a: jax.core.eval_jaxpr(closed.jaxpr, consts, *a)[0]
+            )
+            self._entries[key] = (run, consts)
+        return self._entries[key]
+
+    def __call__(self, *args):
+        run, consts = self._entry(args)
+        return run(consts, *args)
+
+    def lower(self, *args):
+        run, consts = self._entry(args)
+        return run.lower(consts, *args)
+
+
 def compile_frontier(
     db: DeviceDB, plan: ChainPlan | PhysicalPlan,
     block_skipping: str = "auto", use_pallas: bool = True,
@@ -773,7 +786,7 @@ def compile_frontier(
     phys = ensure_lowered(db, plan)
     names = list(phys.param_names)
 
-    @jax.jit
+    @hoisted_jit
     def run(*args):
         params = dict(zip(names, args))
         return execute_ir(
@@ -795,7 +808,7 @@ def compile_frontier(
 class _BatchedFrontierInterp(_FrontierInterp):
     """Frontier semantics with a leading batch axis threaded through the
     walker state: frontiers are [B, dom] matrices and each HopOp is one fused
-    SpMM pass (kernels/fragment_spmm.py) that streams the edge arrays once
+    SpMM pass (kernels/fragment_spmv.py) that streams the edge arrays once
     for all B queries — not a vmap of the whole plan, so the kernel sees the
     batch as a unit. Parameters arrive as [B, 1] columns (broadcast against
     per-entity [dom] and per-edge [E] arrays yields [B, ·]); seed ids reshape
@@ -869,14 +882,14 @@ class _BatchedFrontierInterp(_FrontierInterp):
         return cont(sr.from_mask(m))
 
     def _hop_body(self, w, op: HopOp):
-        # the [B, n_src] frontier reaches the kernel dispatch whole: the block
-        # list is computed from the union of per-row supports (support_mask),
-        # so one SMEM list serves the entire batch
+        # the [B, n_src] frontier reaches the kernel dispatch whole: the
+        # active-chunk list is the union of per-row supports, so one SMEM
+        # list serves the entire batch
         from ..kernels import ops as K
 
-        fused = self.spmm_fused(w, op)
-        if fused is not None:
-            return fused
+        pulled = self.pull_hop(w, op)
+        if pulled is not None:
+            return pulled
         src, dst = op.src_ids, op.dst_ids
         E = src.shape[0]
         if op.measure is not None and self.use_measures:
@@ -896,35 +909,15 @@ class _BatchedFrontierInterp(_FrontierInterp):
             blocks=self.blocks_for(op), block_skipping=self.block_skipping,
         )
 
-    def spmm_fused(self, w, op: HopOp):
-        """Batched decode-fused hop: packed dst/measure columns stream into
-        the SpMM kernel and decode once per block for all B rows. Same layout
-        classification as ``spmv_fused`` (`_packed_layout`); additionally
-        bails (→ dense path) when a measure expression is batch-dependent —
-        a per-row [B, E] dense stream has no fused single-pass formulation."""
+    def pull_hop(self, w, op: HopOp):
         from ..kernels import ops as K
 
-        layout = self._packed_layout(op)
-        if layout is None:
+        kw = self._pull_operands(op)
+        if kw is None:
             return None
-        dst_packed, m_mode, m_operand, m_width, mdict = layout
-        if m_mode == "dense":
-            mv = jnp.asarray(
-                eval_lexpr(op.measure, self.params, self.scalars, self.col),
-                jnp.float32,
-            )
-            if mv.ndim >= 2:  # batch-dependent measure: no shared edge stream
-                return None
-            m_operand = jnp.broadcast_to(mv, (op.src_ids.shape[0],))
         return K.fragment_spmm_packed(
-            w, op.src_ids,
-            op.dst_col.words if dst_packed else op.dst_col.materialize(),
-            m_operand, mdict,
-            n_dst=op.dom_dst,
-            dst_width=op.dst_col.width if dst_packed else 0,
-            m_mode=m_mode, m_width=m_width, op=self.sr.name,
-            use_pallas=self.use_pallas,
-            blocks=self.blocks_for(op), block_skipping=self.block_skipping,
+            w, op=self.sr.name, use_pallas=True,
+            block_skipping=self.block_skipping, **kw,
         )
 
 
@@ -945,7 +938,7 @@ def compile_frontier_batched(
             "batched execution needs at least one query parameter"
         )
 
-    @jax.jit
+    @hoisted_jit
     def run(*args):
         B = args[0].shape[0]
         params = {n: jnp.asarray(a)[:, None] for n, a in zip(names, args)}
@@ -1045,7 +1038,7 @@ def compile_fragment_loop(
     phys = densify_plan(phys)  # scalar loops have no packed path (§Storage)
     names = list(phys.param_names)
 
-    @jax.jit
+    @hoisted_jit
     def run(*args):
         params = dict(zip(names, args))
         return execute_ir(
@@ -1064,30 +1057,37 @@ def compile_fragment_loop(
 def shard_edges(db: DeviceDB, mesh: Mesh, axes: tuple[str, ...]) -> DeviceDB:
     """Pad every index's edge arrays to a multiple of the shard count and place
     them edge-sharded on ``axes``; padding edges carry ``__valid__`` 0 and are
-    masked to the semiring zero inside every hop."""
+    masked to the semiring zero inside every hop.
+
+    The shard trees are always dense. Each array is built on the host from
+    the decoded host index the device columns were encoded from (so packed
+    columns need no device decode), padded there, and put straight into its
+    sharding: every device receives only its own slice."""
     nshards = int(np.prod([mesh.shape[a] for a in axes]))
+    sharding = NamedSharding(mesh, P(axes))
     out: dict[tuple[str, str], DeviceIndex] = {}
-    for key, di in db.indexes.items():
-        E = di.src_ids.shape[0]
+    for (table, key), di in db.indexes.items():
+        hidx = db.host_indexes[(table, key)]
+        E = hidx.num_edges
         pad = (-E) % nshards
-        ew = jnp.concatenate([jnp.ones(E, jnp.float32), jnp.zeros(pad, jnp.float32)])
-        pd = lambda a, fill: jnp.concatenate([a, jnp.full(pad, fill, a.dtype)])
-        sharding = NamedSharding(mesh, P(axes))
-        # materialize per shard: packed columns decode once here (eagerly, at
-        # shard-placement time) — the distributed strategy's documented
-        # fallback; its shard trees are always dense
+
+        def place(a, dtype):
+            a = np.asarray(a, dtype)
+            return jax.device_put(np.concatenate([a, np.zeros(pad, dtype)]), sharding)
+
+        other = next(c for c in hidx.columns if c != key and _is_fk(db.schema, table, c))
         nd = DeviceIndex(
             indptr=di.indptr,
-            src_ids=jax.device_put(pd(di.src_ids, 0), sharding),
-            dst_col=DenseColumn(jax.device_put(pd(di.dst_ids, 0), sharding)),
+            src_ids=place(hidx.src_ids(), np.int32),
+            dst_col=DenseColumn(place(hidx.columns[other].values, np.int32)),
             degrees=di.degrees,
         )
         nd.measure_cols = {
-            m: DenseColumn(jax.device_put(pd(v, 0), sharding))
-            for m, v in di.measures.items()
+            m: DenseColumn(place(hidx.columns[m].values, np.float32))
+            for m in di.measure_cols
         }
-        nd.measure_cols["__valid__"] = DenseColumn(jax.device_put(ew, sharding))
-        out[key] = nd
+        nd.measure_cols["__valid__"] = DenseColumn(place(np.ones(E), np.float32))
+        out[(table, key)] = nd
     return DeviceDB(db.schema, out, db.entity_attrs, db.host_indexes)
 
 
@@ -1103,6 +1103,7 @@ class _DistributedInterp(_FrontierInterp):
 
     early_exit = False
     fuse_kernels = False
+    kernel_hops = False
 
     def __init__(self, params, sr, use_measures=True, *, edges=None, side=None,
                  axes=("data",), frontier_dtype=jnp.float32):
@@ -1129,11 +1130,6 @@ class _DistributedInterp(_FrontierInterp):
 
     attr_col = col
 
-    def spmv_fused(self, w, op: HopOp):
-        # edge data comes from the shard_map argument trees (always dense, see
-        # shard_edges), never from the lower-time column bindings
-        return None
-
     def edge_arrays(self, op: HopOp):
         e = self.edges[f"{op.table}::{op.src_key}"]
         return e["src"], e["dst"], e["m::__valid__"]
@@ -1149,25 +1145,6 @@ class _DistributedInterp(_FrontierInterp):
         return sr.preduce(part.astype(self.frontier_dtype), self.axes).astype(
             jnp.float32
         )
-
-
-def _shard_map_fn():
-    try:
-        return jax.shard_map  # jax >= 0.5 style
-    except AttributeError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map
-
-
-def _shard_map_compat(fn, mesh, in_specs, out_specs):
-    shard_map = _shard_map_fn()
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_vma=False)
-    except TypeError:  # older jax spells the kwarg check_rep
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=False)
 
 
 def compile_frontier_distributed(
@@ -1233,10 +1210,10 @@ def compile_frontier_distributed(
             return jax.vmap(eval_once)(*args)
         return eval_once(*args)
 
-    smapped = _shard_map_compat(
-        run, mesh,
+    smapped = jax.shard_map(
+        run, mesh=mesh,
         in_specs=(edge_specs, side_specs) + tuple(P() for _ in names),
-        out_specs=P(),
+        out_specs=P(), check_vma=False,
     )
     jitted = jax.jit(smapped)
 

@@ -23,29 +23,18 @@ Region formation rules (DESIGN.md §Pipelined fusion):
     whole tail of the plan is one span in profiles;
   * a region must contain either two hops or one hop plus at least one filter
     (a bare single hop gains nothing from fusion and stays as-is);
-  * SeedOp sub-programs (mask seeds) are fused recursively;
-  * under ``mode='auto'`` a two-hop region only forms when its reach matrix
-    is sparse enough (``REACH_DENSITY_MAX``) — dense reach means the fused
-    pass would stream nearly every hop2 block regardless of the realized
-    intermediate support, while the unfused composition plans hop2's block
-    list from the frontier it just materialized; ``mode='on'`` fuses
-    unconditionally.
+  * SeedOp sub-programs (mask seeds) are fused recursively.
 
-For two-hop regions we also precompute a host-side block-to-block
-reachability matrix ``reach[nb1, nb2]``: hop1's edge block ``b1`` reaches
-hop2's edge block ``b2`` iff some dst produced by ``b1`` falls inside
-``b2``'s ``[src_min, src_max]`` range. At dispatch time hop2's active block
-list is the OR of the reach rows of hop1's active blocks — conservative
-(a skipped hop2 block provably contributes only ⊕-identity), so block
-skipping composes with fusion without reading the intermediate frontier.
+Whether a formed region runs fused is decided at dispatch
+(``kernels/ops.py``): ``fusion='auto'`` runs it unfused when the resident
+``(8, n_mid)`` intermediate would exceed ``FUSED_VMEM_BUDGET_BYTES``.
+Both phases stream every edge block of their destination-sorted edges;
+skipping is by active frontier chunk, as in the unfused kernel.
 """
 from __future__ import annotations
 
 import dataclasses
 
-import numpy as np
-
-from ..kernels.params import EDGE_BLOCK
 from .lower import (
     EntityFilterOp,
     FusedHopOp,
@@ -56,12 +45,6 @@ from .lower import (
 )
 
 
-#: 'auto' fuses a two-hop region only when the mean reach density is below
-#: this — above it the reach-derived hop2 block list approaches a full scan
-#: and the unfused support-planned composition wins.
-REACH_DENSITY_MAX = 0.5
-
-
 def _pure_mask_filter(op) -> bool:
     return (
         isinstance(op, EntityFilterOp)
@@ -70,37 +53,7 @@ def _pure_mask_filter(op) -> bool:
     )
 
 
-def _block_reach(hop1: HopOp, hop2: HopOp):
-    """``bool[nb1, nb2]``: which hop2 edge blocks can hop1 block b1 touch.
-
-    hop2's blocks are CSR-ordered, so their ``[src_min, src_max]`` ranges are
-    monotone: the blocks containing a given src value form one contiguous run,
-    found with two searchsorteds; runs are accumulated per hop1 block with a
-    difference array (O(E1·log nb2 + nb1·nb2) host work, done once at fuse
-    time)."""
-    if hop2.block_src_min is None or hop2.block_src_max is None:
-        return None
-    dst1 = np.asarray(hop1.dst_ids)
-    smin2 = np.asarray(hop2.block_src_min)
-    smax2 = np.asarray(hop2.block_src_max)
-    nb2 = int(smin2.shape[0])
-    e1 = int(dst1.shape[0])
-    nb1 = max(1, -(-e1 // EDGE_BLOCK))
-    reach = np.zeros((nb1, nb2), dtype=bool)
-    for b1 in range(nb1):
-        vals = dst1[b1 * EDGE_BLOCK:(b1 + 1) * EDGE_BLOCK]
-        if vals.size == 0:
-            continue
-        starts = np.searchsorted(smax2, vals, side="left")
-        ends = np.searchsorted(smin2, vals, side="right")
-        diff = np.zeros(nb2 + 1, dtype=np.int64)
-        np.add.at(diff, starts, 1)
-        np.add.at(diff, ends, -1)
-        reach[b1] = np.cumsum(diff[:nb2]) > 0
-    return reach
-
-
-def _form_regions(ops: tuple, mode: str) -> tuple:
+def _form_regions(ops: tuple) -> tuple:
     out: list = []
     i = 0
     n = len(ops)
@@ -124,39 +77,26 @@ def _form_regions(ops: tuple, mode: str) -> tuple:
             out.append(op)
             i += 1
             continue
-        reach = _block_reach(op, second) if second is not None else None
-        if (
-            mode == "auto"
-            and second is not None
-            and (reach is None or reach.mean() > REACH_DENSITY_MAX)
-        ):
-            # dense (or unknown) reach: the fused hop2 phase would touch
-            # ~every block; keep the support-planned unfused composition
-            out.append(op)
-            i += 1
-            continue
         if j < n and isinstance(ops[j], GroupOp) and j == n - 1:
             members.append(ops[j])
             j += 1
         n_mid = op.dom_dst
-        out.append(FusedHopOp(tuple(members), n_mid, reach))
+        out.append(FusedHopOp(tuple(members), n_mid))
         i = j
     return tuple(out)
 
 
-def fuse_plan(phys: PhysicalPlan, mode: str = "on") -> PhysicalPlan:
+def fuse_plan(phys: PhysicalPlan) -> PhysicalPlan:
     """Return a plan with fusable op runs collapsed into FusedHopOp regions
-    (idempotent; plans with no fusable run come back unchanged). ``mode``:
-    'on' fuses every eligible region; 'auto' additionally applies the reach
-    density guard (see module docstring)."""
+    (idempotent; plans with no fusable run come back unchanged)."""
     ops = []
     for op in phys.ops:
         if isinstance(op, SeedOp) and op.programs:
             op = dataclasses.replace(
-                op, programs=tuple(fuse_plan(p, mode) for p in op.programs)
+                op, programs=tuple(fuse_plan(p) for p in op.programs)
             )
         ops.append(op)
-    fused = _form_regions(tuple(ops), mode)
+    fused = _form_regions(tuple(ops))
     return dataclasses.replace(phys, ops=fused)
 
 

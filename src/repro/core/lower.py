@@ -200,10 +200,25 @@ class HopOp:
     # None when the index was built without it → hop always full-scans
     block_src_min: Any = None
     block_src_max: Any = None
+    # the same edges sorted by destination, for the hop kernel (PullBinding)
+    pull: "PullBinding | None" = None
 
     @property
     def dst_ids(self):
         return self.dst_col.materialize()
+
+
+@dataclass(eq=False)
+class PullBinding:
+    """The hop's edges as stored by the index keyed on its *destination*
+    (``I_{table.dst_key}``): destinations sorted (that index's expanded row
+    ids), sources in its dst column (any DeviceColumn kind), and the measure
+    rebound to that index's columns. The hop kernel streams this order, so
+    every 128-edge row scatters into one or two destination chunks."""
+
+    dst_ids: Any  # int32[E], sorted
+    src_col: Any  # repro.storage.DeviceColumn
+    measure: LExpr | None = None
 
 
 @dataclass(eq=False)
@@ -245,14 +260,10 @@ class FusedHopOp:
 
     ``members`` is the original op sub-sequence (order preserved), so any
     interpreter without a fused kernel path replays them one by one and gets
-    bit-identical results. ``reach`` is an optional host-precomputed
-    ``bool[nb1, nb2]`` block-to-block reachability matrix: hop2's active block
-    list is derived from hop1's by OR-ing the rows of hop1's active blocks
-    (conservative: a skipped hop2 block provably reads only ⊕-identity)."""
+    bit-identical results."""
 
     members: tuple  # (HopOp | EntityFilterOp | GroupOp, ...)
     n_mid: int  # intermediate entity domain (hop1.dom_dst)
-    reach: Any = None  # np.bool_[nb1, nb2] | None
 
     @property
     def hops(self) -> tuple:
@@ -351,6 +362,7 @@ def lower(db, plan: ChainPlan) -> PhysicalPlan:
                 measure=measure, semijoin=s.semijoin,
                 block_src_min=getattr(di, "block_src_min", None),
                 block_src_max=getattr(di, "block_src_max", None),
+                pull=_pull_binding(db, s.table, s.src_key, measure),
             ))
         else:  # EntityStep
             factor = (
@@ -411,6 +423,30 @@ def _seed_scalar_capture(db, plan: ChainPlan, seed: SeedIds) -> dict:
                     db.entity_attrs[(seed.entity, r.attr)],
                 )
     return scalars
+
+
+def _pull_binding(db, table: str, src_key: str, measure) -> PullBinding | None:
+    """Bind the reverse index of a hop (None when the database holds only
+    this direction)."""
+    rel = db.schema.relationships[table]
+    dst_key = rel.fk2 if src_key == rel.fk1 else rel.fk1
+    rev = db.indexes.get((table, dst_key))
+    if rev is None:
+        return None
+
+    def rebind(e):
+        if isinstance(e, LCol) and e.key[0] == "edge":
+            attr = e.key[3]
+            return LCol(("edge", table, dst_key, attr), rev.measure_cols[attr])
+        if isinstance(e, LBin):
+            return LBin(e.op, rebind(e.left), rebind(e.right))
+        if isinstance(e, LCall):
+            return LCall(e.fn, tuple(rebind(a) for a in e.args))
+        return e
+
+    return PullBinding(
+        rev.src_ids, rev.dst_col, rebind(measure) if measure is not None else None
+    )
 
 
 def _lower_expr(db, e: Expr, step, plan: ChainPlan) -> LExpr:

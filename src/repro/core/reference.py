@@ -49,12 +49,23 @@ class ExecStats:
     hops: int = 0
 
 
+#: Aggregates whose paths may be summed per destination after every hop.
+LINEAR_AGGS = ("count", "sum", "exists")
+
+
 class NumpyQueryEngine:
-    def __init__(self, schema: Schema, lookup: str = "index", agg: str = "dense"):
+    """``collapse=True`` merges the paths that reach the same entity after
+    every hop (their weights add), which bounds each hop by the relation
+    size instead of the path count — the form that answers SUM/COUNT/EXISTS
+    queries at deployment sizes. Other aggregates always enumerate paths."""
+
+    def __init__(self, schema: Schema, lookup: str = "index", agg: str = "dense",
+                 collapse: bool = False):
         assert lookup in ("index", "binary", "scan") and agg in ("dense", "hash")
         self.schema = schema
         self.lookup = lookup
         self.agg = agg
+        self.collapse = collapse
         self.copies: dict[tuple[str, str], _SortedCopy] = {}
         for rel in schema.relationships.values():
             for key in (rel.fk1, rel.fk2):
@@ -77,9 +88,13 @@ class NumpyQueryEngine:
     def execute_plan(self, plan: ChainPlan, params: dict[str, Any]) -> np.ndarray:
         self.stats = ExecStats()
         ids, w, scalars = self._seed(plan, params)
+        collapse = self.collapse and plan.agg in LINEAR_AGGS
         for s in plan.steps:
             if isinstance(s, RelHop):
                 ids, w = self._hop(s, ids, w, params, scalars)
+                if collapse:
+                    ids, inv = np.unique(ids, return_inverse=True)
+                    w = np.bincount(inv, weights=w, minlength=ids.shape[0])
             else:
                 ids, w = self._entity_step(s, ids, w, params, scalars)
             self.stats.materialized_elements += ids.shape[0]

@@ -20,9 +20,24 @@ def _zipf_choice(rng: np.random.Generator, n: int, size: int, s: float = 1.1) ->
 
 
 def _dedupe_pairs(a: np.ndarray, b: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    key = a.astype(np.int64) * (b.max() + 1) + b
-    _, idx = np.unique(key, return_index=True)
-    return a[idx], b[idx]
+    """Distinct (a, b) pairs, sorted by (a, b)."""
+    m = b.max() + 1
+    key = np.unique(a.astype(np.int64) * m + b)
+    return (key // m).astype(a.dtype), (key % m).astype(b.dtype)
+
+
+def _distinct_pairs(rng, n_docs: int, n_other: int, target: int, s: float):
+    """Exactly ``target`` distinct (doc, other) pairs: uniform docs, Zipf
+    ``other``; duplicates are redrawn, then a uniform subset is kept."""
+    a = b = np.zeros(0, np.int64)
+    while a.shape[0] < target:
+        n = int((target - a.shape[0]) * 1.4) + 16
+        a, b = _dedupe_pairs(
+            np.concatenate([a, rng.integers(0, n_docs, size=n)]),
+            np.concatenate([b, _zipf_choice(rng, n_other, n, s)]),
+        )
+    keep = np.sort(rng.choice(a.shape[0], target, replace=False))
+    return a[keep], b[keep]
 
 
 def make_pubmed(
@@ -34,21 +49,30 @@ def make_pubmed(
     zipf_term: float = 1.1,
     fre_zipf: float = 1.5,
     seed: int = 0,
+    exact_counts: bool = False,
 ) -> Schema:
     """PubMed-M/MS-shaped schema: DT(Doc, Term, Fre), DA(Doc, Author),
-    Document(ID, Year). Raise ``n_terms`` (lower term fanout) for the -MS flavor."""
+    Document(ID, Year). Raise ``n_terms`` (lower term fanout) for the -MS
+    flavor. Duplicate pairs are dropped, so DT/DA hold fewer rows than
+    ``n_docs × avg`` unless ``exact_counts`` redraws them."""
     rng = np.random.default_rng(seed)
 
     e_dt = int(n_docs * avg_terms_per_doc)
-    dt_doc = rng.integers(0, n_docs, size=e_dt)
-    dt_term = _zipf_choice(rng, n_terms, e_dt, zipf_term)
-    dt_doc, dt_term = _dedupe_pairs(dt_doc, dt_term)
+    e_da = int(n_docs * avg_authors_per_doc)
+    if exact_counts:
+        dt_doc, dt_term = _distinct_pairs(rng, n_docs, n_terms, e_dt, zipf_term)
+    else:
+        dt_doc = rng.integers(0, n_docs, size=e_dt)
+        dt_term = _zipf_choice(rng, n_terms, e_dt, zipf_term)
+        dt_doc, dt_term = _dedupe_pairs(dt_doc, dt_term)
     fre = 1 + _zipf_choice(rng, 50, dt_doc.shape[0], fre_zipf)
 
-    e_da = int(n_docs * avg_authors_per_doc)
-    da_doc = rng.integers(0, n_docs, size=e_da)
-    da_author = _zipf_choice(rng, n_authors, e_da, 1.05)
-    da_doc, da_author = _dedupe_pairs(da_doc, da_author)
+    if exact_counts:
+        da_doc, da_author = _distinct_pairs(rng, n_docs, n_authors, e_da, 1.05)
+    else:
+        da_doc = rng.integers(0, n_docs, size=e_da)
+        da_author = _zipf_choice(rng, n_authors, e_da, 1.05)
+        da_doc, da_author = _dedupe_pairs(da_doc, da_author)
 
     year = rng.integers(1990, 2016, size=n_docs)
 
@@ -71,6 +95,33 @@ def make_pubmed(
     )
     schema.validate()
     return schema
+
+
+#: PubMed-M at full scale (paper Table 1).
+PUBMED_TABLE1 = dict(
+    n_docs=23_326_299,
+    n_terms=27_883,
+    n_authors=6_301_521,
+    dt_edges=901_388_401,
+    da_edges=61_329_130,
+)
+
+
+def pubmed_table1_scale(n_docs: int, seed: int = 0) -> Schema:
+    """PubMed at the ratios of Table 1 for ``n_docs`` documents: the MeSH
+    vocabulary at its full 27,883 terms, and authors, DT rows and DA rows per
+    document as in the full corpus (0.270, 38.64 and 2.63). The deployment
+    size of ``chip_smoke.py`` and ``python -m repro.launch.serve --docs``."""
+    t = PUBMED_TABLE1
+    return make_pubmed(
+        n_docs=n_docs,
+        n_terms=t["n_terms"],
+        n_authors=max(1, round(n_docs * t["n_authors"] / t["n_docs"])),
+        avg_terms_per_doc=t["dt_edges"] / t["n_docs"],
+        avg_authors_per_doc=t["da_edges"] / t["n_docs"],
+        seed=seed,
+        exact_counts=True,
+    )
 
 
 def make_semmeddb(

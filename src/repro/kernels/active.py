@@ -1,11 +1,11 @@
 """Frontier-sparsity metadata: per-block src ranges and active-block lists.
 
 GQ-Fast's selective-query win (paper §4-5) comes from touching only the index
-*fragments* reachable from the active sources. The streaming kernels
-(:mod:`.fragment_spmv`, :mod:`.fragment_spmv_packed`, :mod:`.fragment_spmm`)
-instead scan every ``EDGE_BLOCK``-edge block per hop — a 1-seed query over a
-10M-edge index pays a full-domain scan. This module is the machinery that
-restores fragment-level selectivity at block granularity:
+*fragments* reachable from the active sources. The hop kernel
+(:mod:`.fragment_spmv`) streams ``EDGE_BLOCK``-edge blocks; for source-sorted
+edges this module restores fragment-level selectivity at block granularity
+(the served path, whose edges are destination-sorted, skips by frontier
+chunk instead — see :mod:`.ops`):
 
   * :func:`block_ranges` — build-time (host, numpy): for each EDGE_BLOCK-sized
     block of the CSR-ordered edge arrays, its ``[src_min, src_max]`` source-id

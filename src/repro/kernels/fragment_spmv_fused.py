@@ -1,44 +1,25 @@
-"""Pallas TPU kernels: pipelined 2-hop fused fragment join-aggregate.
+"""Pallas TPU kernel: pipelined 2-hop fused fragment join-aggregate.
 
 GQ-Fast's bottom-up execution is *fully pipelined* — intermediate results are
-never materialized. These kernels execute a whole
+never materialized. This kernel executes a whole
 :class:`repro.core.lower.FusedHopOp` region in ONE grid pass: the first hop's
-output frontier accumulates in a VMEM scratch buffer ``u``, the region's
+output frontier accumulates in a VMEM scratch group ``u`` (``(8, n_mid)``,
+the same layout as a frontier group of :mod:`.fragment_spmv`), the region's
 constant filter mask (and the second hop's semijoin binarize) is applied to
-``u`` in-register at the phase boundary, and the second hop streams its edge
-blocks against the VMEM-resident ``u`` — the intermediate ``[n_mid]`` vector
-never round-trips through HBM, and the two hops cost one kernel launch
-instead of two launches plus a frontier read-back.
+``u`` at the phase boundary, and the second hop streams its edge blocks
+against the VMEM-resident ``u`` — the intermediate never round-trips through
+HBM, and the two hops cost one kernel launch instead of two.
 
-Grid layout: ``C1 + 1 + C2`` steps, where ``C1``/``C2`` are the lengths of
-the two scalar-prefetched block lists (kernels/active.py). Steps ``< C1`` run
-hop1 (accumulate into ``u``), the dedicated step ``C1`` applies the mid
-mask/binarize, steps ``> C1`` run hop2 (accumulate into the output). Each
-step picks its phase with a value-level ``lax.switch`` over PURE branches
-(every ref read is hoisted above the switch): after discharge the switch
-stays a real conditional, so a grid step executes only its own phase's
-gather/scatter — and steps past a phase's ``n_active`` take the idle branch
-and cost almost nothing. This is what makes runtime block skipping effective
-even in the traced tier, where grids cannot shrink: the unfused kernels'
-``pl.when`` guards discharge to selects whose both-sides compute runs at
-every step regardless. Both phases reuse the packed operand layout and
-per-block decode of :mod:`.fragment_spmv_packed` (``_packed_operands``), so
-dense and BCA-packed streams fuse identically and results stay bit-identical
-to the unfused two-kernel path on every semiring × encoding × skip-mode
-combination. The degenerate 1-hop+filter region runs a ``C1 + 1`` grid: hop1
-accumulates into ``u`` and the final step applies the mask and writes the
-output.
-
-Block skipping composes: hop1's list comes from the incoming frontier's
-support (as in the unfused active kernels); hop2's list is derived *without
-reading u* from the fuse-time block reachability matrix
-(:func:`repro.core.fuse._block_reach`) — the OR of the rows of hop1's active
-blocks. Skipping off simply passes full ``arange`` lists, so one kernel body
-serves every mode.
-
-Padding contract: identical to the unfused kernels — hop1's src pads one past
-the frontier, hop2's src pads one past ``n_mid`` (``u``'s gather fills the
-⊕-identity), packed word streams pad with zero words.
+Grid layout: ``(batch groups, C1 + 1 + C2)``, where ``C1``/``C2`` are the
+two hops' edge-block counts. Steps ``< C1`` run hop1 into ``u``; the step
+``C1`` applies the mid mask/binarize and compacts ``u``'s active 128-lane
+chunks into an SMEM list (hop2's gather reads only those, as the unfused
+kernel does from its prefetched list); steps ``> C1`` run hop2 into the
+output. Both phases run the per-block body of the unfused kernel
+(:func:`.fragment_spmv.hop_block`), so results are bit-identical to the
+unfused two-kernel composition on every semiring × encoding × skip mode. The
+degenerate 1-hop+filter region runs a ``C1 + 1`` grid whose last step writes
+the masked ``u`` to the output.
 """
 from __future__ import annotations
 
@@ -49,17 +30,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .bitunpack import decode_groups
-from .fragment_spmm import _edge_product_batched, _segment_combine_batched
-from .fragment_spmv import IDENTITY, _combine, _edge_product, _segment_combine
-from .fragment_spmv_packed import (
-    GROUPS_PER_EDGE_BLOCK,
-    _packed_operands,
+from .fragment_spmv import (
+    GROUP_ROWS,
+    IDENTITY,
+    LANES,
+    BlockStreams,
+    HopCfg,
+    chunk_lists,
+    compiler_params,
+    edge_operands,
+    hop_block,
+    n_chunks,
+    n_edges,
+    pad_frontier,
+    padded,
+    resident_spec,
+    row_scratch,
+    stream_specs,
 )
-from .params import EDGE_BLOCK
 
 
-def _binarize(w, op: str):
+def binarize(w, op: str):
     """Semijoin ⋉ on a raw frontier vector — mirrors ``Semiring.binarize``
     exactly (bit-identity with the unfused path depends on it)."""
     if op == "sum":
@@ -68,328 +59,166 @@ def _binarize(w, op: str):
     return jnp.where(w != zero, jnp.float32(1.0), jnp.float32(zero))
 
 
-def _apply_mask(w, keep, op: str):
+def apply_mask(w, keep, op: str):
     """Predicate filter — mirrors ``Semiring.mask``: keep where >0, else 0̄."""
     return jnp.where(keep > 0, w, IDENTITY[op])
 
 
-def _n_hop_refs(m_mode: str) -> int:
-    """Refs per hop operand set (src + dst + optional measure (+ dict));
-    the fused hop sets never carry the resident frontier."""
-    return 2 + (m_mode != "none") + (m_mode == "dict")
+def _compact_chunks(u_ref, clist, ccnt, n_mid: int, op: str) -> None:
+    """SMEM chunk list + prefix counts of ``u``'s non-identity chunks — the
+    in-kernel twin of :func:`.fragment_spmv.chunk_lists`."""
+    zero = IDENTITY[op]
+    nc = n_chunks(n_mid)
+
+    def body(c, count):
+        x = u_ref[:, pl.ds(pl.multiple_of(c * LANES, LANES), LANES)]
+        ccnt[c] = count
+        clist[count] = c
+        return count + jnp.any(x != zero).astype(jnp.int32)
+
+    ccnt[nc] = jax.lax.fori_loop(0, nc, body, jnp.int32(0))
 
 
-def _decode_vals(dst_width: int, m_mode: str, m_width: int, dst, rest):
-    """Value-level twin of ``fragment_spmv_packed._decode_block``: one edge
-    block's (dst, measure) from already-read VALUES. Pure, so it can live
-    inside a ``lax.switch`` branch — a ref read inside a branch would force
-    the discharge back to select-over-all-branches and every step would pay
-    for both phases again."""
-    if dst_width:
-        dst = decode_groups(dst, dst_width).reshape(-1)
-    if m_mode == "none":
-        m = jnp.ones(EDGE_BLOCK, jnp.float32)
-    elif m_mode == "dense":
-        m = rest[0]
-    else:
-        idx = decode_groups(rest[0], m_width).reshape(-1)
-        if m_mode == "dict":
-            m = jnp.take(rest[1], idx)
-        else:
-            m = idx.astype(jnp.float32)
-    return dst, m
-
-
-def _phase_specs(kinds, pick):
-    """BlockSpecs for one phase of the fused grid. ``pick(i, bi1, bi2)``
-    selects the edge block: phase 1 clamps into ``bi1``, phase 2 into ``bi2``
-    (during the other phase the clamp re-fetches a valid block; no compute
-    reads it). Index maps see the 4 prefetched scalars (na1, bi1, na2, bi2)."""
-    specs = []
-    for k in kinds:
-        if k == "edge":
-            specs.append(pl.BlockSpec(
-                (EDGE_BLOCK,),
-                lambda i, na1, bi1, na2, bi2, _p=pick: (_p(i, bi1, bi2),),
-            ))
-        elif k[0] == "resident":
-            shape = k[1]
-            specs.append(pl.BlockSpec(
-                shape, lambda i, na1, bi1, na2, bi2, _z=(0,) * len(shape): _z
-            ))
-        else:  # ('words', width)
-            specs.append(pl.BlockSpec(
-                (GROUPS_PER_EDGE_BLOCK, k[1]),
-                lambda i, na1, bi1, na2, bi2, _p=pick: (_p(i, bi1, bi2), 0),
-            ))
-    return specs
-
-
-def _kernel_fused2(
-    C1: int, n_mid: int, n_dst: int, op: str,
-    cfg1: tuple, cfg2: tuple, has_mask: bool, mid_binarize: bool,
-    batched: bool, *refs,
-):
-    dw1, mm1, mw1 = cfg1
-    dw2, mm2, mw2 = cfg2
-    na1_ref, _bi1, na2_ref, _bi2, w_ref, *rest = refs
-    n1 = _n_hop_refs(mm1)
-    n2 = _n_hop_refs(mm2)
+def _fused_kernel(C1, cfg1, cfg2, E1, E2, op, has_mask, mid_binarize, *refs):
+    two_hop = cfg2 is not None
+    cl1, cc1, w_ref, *rest = refs
+    n1 = cfg1.n_operands()
+    n2 = cfg2.n_operands() if two_hop else 0
     h1, h2 = rest[:n1], rest[n1:n1 + n2]
     k = n1 + n2
     mask_ref = rest[k] if has_mask else None
-    out_ref = rest[k + int(has_mask)]
-    u_ref = rest[k + int(has_mask) + 1]
-    ep = _edge_product_batched if batched else _edge_product
-    seg = _segment_combine_batched if batched else _segment_combine
-    i = pl.program_id(0)
-    zero = jnp.float32(IDENTITY[op])
+    k += has_mask
+    out_ref, u_ref = rest[k], rest[k + 1]
+    rows = rest[k + 2:k + 5]
+    cl2, cc2 = rest[k + 5:k + 7] if two_hop else (None, None)
+    i = pl.program_id(1)
 
-    # every ref read happens HERE, above the switch — the branches must stay
-    # pure value functions or the discharge lowers the switch to a select
-    # that computes all four branches at every step
-    u = jnp.where(i == 0, zero, u_ref[...])
-    out = jnp.where(i == 0, zero, out_ref[...])
-    w = w_ref[...]
-    b1 = [r[...] for r in h1]
-    b2 = [r[...] for r in h2]
-    keep = mask_ref[...] if has_mask else None
+    @pl.when(i == 0)
+    def _init():
+        u_ref[...] = jnp.full(u_ref.shape, IDENTITY[op], jnp.float32)
+        out_ref[...] = jnp.full(out_ref.shape, IDENTITY[op], jnp.float32)
 
-    def hop1(u, out):
-        dst, m = _decode_vals(dw1, mm1, mw1, b1[1], b1[2:])
-        prod = ep(w, b1[0], m, op)
-        return _combine(u, seg(prod, dst, n_mid, op), op), out
+    @pl.when(i < C1)
+    def _hop1():
+        streams = BlockStreams(cfg1, h1, rows, E1, i)
+        hop_block(streams, w_ref, cl1, cc1, u_ref, op)
 
-    def mid(u, out):
+    @pl.when(i == C1)
+    def _mid():
+        u = u_ref[...]
         if has_mask:
-            u = _apply_mask(u, keep[None, :] if batched else keep, op)
+            u = apply_mask(u, mask_ref[...], op)
+        if not two_hop:
+            out_ref[...] = u
+            return
         if mid_binarize:
-            u = _binarize(u, op)
-        return u, out
+            u = binarize(u, op)
+        u_ref[...] = u
+        _compact_chunks(u_ref, cl2, cc2, cfg1.n_dst, op)
 
-    def hop2(u, out):
-        dst, m = _decode_vals(dw2, mm2, mw2, b2[1], b2[2:])
-        prod = ep(u, b2[0], m, op)
-        return u, _combine(out, seg(prod, dst, n_dst, op), op)
-
-    def idle(u, out):
-        return u, out
-
-    branch = jnp.where(
-        i < C1,
-        jnp.where(i < na1_ref[0], 0, 3),
-        jnp.where(i == C1, 1, jnp.where(i - C1 - 1 < na2_ref[0], 2, 3)),
-    )
-    u, out = jax.lax.switch(branch, [hop1, mid, hop2, idle], u, out)
-    u_ref[...] = u
-    out_ref[...] = out
-
-
-def _kernel_fused1(
-    C1: int, n_dst: int, op: str, cfg1: tuple, has_mask: bool,
-    batched: bool, *refs,
-):
-    """Degenerate 1-hop+filter region (``C1 + 1`` grid): accumulate in VMEM
-    scratch, then the dedicated final step applies the output-domain mask
-    in-register and writes out. Same value-level switch structure as
-    :func:`_kernel_fused2` so inactive steps stay cheap."""
-    dw1, mm1, mw1 = cfg1
-    na1_ref, _bi1, w_ref, *rest = refs
-    n1 = _n_hop_refs(mm1)
-    h1 = rest[:n1]
-    mask_ref = rest[n1] if has_mask else None
-    out_ref = rest[n1 + int(has_mask)]
-    u_ref = rest[n1 + int(has_mask) + 1]
-    ep = _edge_product_batched if batched else _edge_product
-    seg = _segment_combine_batched if batched else _segment_combine
-    i = pl.program_id(0)
-    zero = jnp.float32(IDENTITY[op])
-
-    u = jnp.where(i == 0, zero, u_ref[...])
-    out = jnp.where(i == 0, zero, out_ref[...])
-    w = w_ref[...]
-    b1 = [r[...] for r in h1]
-    keep = mask_ref[...] if has_mask else None
-
-    def hop1(u, out):
-        dst, m = _decode_vals(dw1, mm1, mw1, b1[1], b1[2:])
-        prod = ep(w, b1[0], m, op)
-        return _combine(u, seg(prod, dst, n_dst, op), op), out
-
-    def final(u, out):
-        o = u
-        if has_mask:
-            o = _apply_mask(o, keep[None, :] if batched else keep, op)
-        return u, o
-
-    def idle(u, out):
-        return u, out
-
-    branch = jnp.where(i < C1, jnp.where(i < na1_ref[0], 0, 2), 1)
-    u, out = jax.lax.switch(branch, [hop1, final, idle], u, out)
-    u_ref[...] = u
-    out_ref[...] = out
-
-
-def _mask_spec(n_mid: int, num_prefetch: int):
-    if num_prefetch == 4:
-        return pl.BlockSpec((n_mid,), lambda i, na1, bi1, na2, bi2: (0,))
-    return pl.BlockSpec((n_mid,), lambda i, na, bi: (0,))
-
-
-def _clamped_specs(kinds, C1: int):
-    """Degenerate-region BlockSpecs (2 prefetch scalars): the final mask step
-    at ``i == C1`` has no block of its own, so the pick clamps into ``bi``."""
-    specs = []
-    for k in kinds:
-        if k == "edge":
-            specs.append(pl.BlockSpec(
-                (EDGE_BLOCK,),
-                lambda i, na, bi: (bi[jnp.minimum(i, C1 - 1)],),
-            ))
-        elif k[0] == "resident":
-            shape = k[1]
-            specs.append(pl.BlockSpec(
-                shape, lambda i, na, bi, _z=(0,) * len(shape): _z
-            ))
-        else:  # ('words', width)
-            specs.append(pl.BlockSpec(
-                (GROUPS_PER_EDGE_BLOCK, k[1]),
-                lambda i, na, bi: (bi[jnp.minimum(i, C1 - 1)], 0),
-            ))
-    return specs
+    if two_hop:
+        @pl.when(i > C1)
+        def _hop2():
+            streams = BlockStreams(cfg2, h2, rows, E2, i - C1 - 1)
+            hop_block(streams, u_ref, cl2, cc2, out_ref, op)
 
 
 def _fused_call(
-    weights,
-    src1, dst1, m1, md1,
-    src2, dst2, m2, md2,
-    mid_mask,
-    block_idx1, n_active1, block_idx2, n_active2,
-    n_mid, n_dst, cfg1, cfg2, op, mid_binarize, interpret, batched,
+    weights, hop1, hop2, mid_mask, *,
+    n_mid, n_dst, cfg1_modes, cfg2_modes, op, mid_binarize, skip_chunks,
+    interpret,
 ):
     if op not in IDENTITY:
         raise ValueError(f"unknown combine op {op!r}")
-    two_hop = src2 is not None
+    B, n_src = weights.shape
+    two_hop = hop2 is not None
     has_mask = mid_mask is not None
-    E1 = src1.shape[0]
-    pad1 = (-E1) % EDGE_BLOCK
-    nb1 = max(1, (E1 + pad1) // EDGE_BLOCK)
-    ops1, kinds1 = _packed_operands(
-        weights, src1, dst1, m1, md1, *cfg1, nb1, pad1,
-    )
-    C1 = int(block_idx1.shape[0])
-    out_shape = (weights.shape[0], n_dst) if batched else (n_dst,)
-    u_shape = (weights.shape[0], n_mid) if batched else (n_mid,)
-    if not two_hop:
-        in_specs = _clamped_specs(kinds1, C1)
-        operands = list(ops1)
-        if has_mask:
-            in_specs.append(_mask_spec(n_mid, 2))
-            operands.append(mid_mask)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(C1 + 1,),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(out_shape, lambda i, na, bi: (0,) * len(out_shape)),
-            scratch_shapes=[pltpu.VMEM(u_shape, jnp.float32)],
-        )
-        return pl.pallas_call(
-            functools.partial(_kernel_fused1, C1, n_dst, op, cfg1, has_mask, batched),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
-            interpret=interpret,
-        )(n_active1, block_idx1, *operands)
-    E2 = src2.shape[0]
-    pad2 = (-E2) % EDGE_BLOCK
-    nb2 = max(1, (E2 + pad2) // EDGE_BLOCK)
-    ops2, kinds2 = _packed_operands(
-        None, src2, dst2, m2, md2, *cfg2, nb2, pad2, n_src=n_mid,
-    )
-    C2 = int(block_idx2.shape[0])
 
-    def pick1(i, bi1, bi2):
-        return bi1[jnp.minimum(i, C1 - 1)]
+    def cfg_of(hop, modes, n_in, n_out):
+        sw, dw, mm, mw = modes
+        return HopCfg(n_in, n_out, sw, dw, mm, mw,
+                      0 if hop[3] is None else int(hop[3].shape[0]))
 
-    def pick2(i, bi1, bi2):
-        return bi2[jnp.clip(i - C1 - 1, 0, C2 - 1)]
-
-    in_specs = _phase_specs(kinds1, pick1) + _phase_specs(kinds2, pick2)
-    operands = list(ops1) + list(ops2)
+    cfg1 = cfg_of(hop1, cfg1_modes, n_src, n_mid)
+    E1 = n_edges(hop1[0], cfg1.src_width, hop1[1], cfg1.dst_width)
+    ops1, C1 = edge_operands(cfg1, E1, *hop1)
+    wp = pad_frontier(jnp.asarray(weights, jnp.float32), n_src, op)
+    cl1, cc1 = chunk_lists(wp, op, skip_chunks)
+    G = wp.shape[0] // GROUP_ROWS
+    out_n = n_dst if two_hop else n_mid
+    mask_ops, mask_specs = [], []
     if has_mask:
-        in_specs.append(_mask_spec(n_mid, 4))
-        operands.append(mid_mask)
+        mm = jnp.asarray(mid_mask, jnp.float32).reshape(1, -1)
+        mask_ops = [jnp.pad(mm, ((0, 0), (0, padded(n_mid) - n_mid)))]
+        mask_specs = [pl.BlockSpec((1, padded(n_mid)), lambda g, i, *pf: (0, 0))]
+    scratch = [pltpu.VMEM((GROUP_ROWS, padded(n_mid)), jnp.float32)] + row_scratch()
+    if two_hop:
+        cfg2 = cfg_of(hop2, cfg2_modes, n_mid, n_dst)
+        E2 = n_edges(hop2[0], cfg2.src_width, hop2[1], cfg2.dst_width)
+        ops2, C2 = edge_operands(cfg2, E2, *hop2)
+        in_specs = (
+            [resident_spec(n_src)]
+            + stream_specs(cfg1, lambda i, *_: jnp.minimum(i, C1 - 1))
+            + stream_specs(cfg2, lambda i, *_: jnp.clip(i - C1 - 1, 0, C2 - 1))
+            + mask_specs
+        )
+        operands = [wp, *ops1, *ops2, *mask_ops]
+        scratch += [pltpu.SMEM((n_chunks(n_mid),), jnp.int32),
+                    pltpu.SMEM((n_chunks(n_mid) + 1,), jnp.int32)]
+        steps = C1 + 1 + C2
+    else:
+        cfg2, E2 = None, 0
+        in_specs = (
+            [resident_spec(n_src)]
+            + stream_specs(cfg1, lambda i, *_: jnp.minimum(i, C1 - 1))
+            + mask_specs
+        )
+        operands = [wp, *ops1, *mask_ops]
+        steps = C1 + 1
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(C1 + 1 + C2,),
+        num_scalar_prefetch=2,
+        grid=(G, steps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            out_shape, lambda i, na1, bi1, na2, bi2: (0,) * len(out_shape)
-        ),
-        scratch_shapes=[pltpu.VMEM(u_shape, jnp.float32)],
+        out_specs=resident_spec(out_n),
+        scratch_shapes=scratch,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
-            _kernel_fused2, C1, n_mid, n_dst, op, cfg1, cfg2,
-            has_mask, mid_binarize, batched,
+            _fused_kernel, C1, cfg1, cfg2, E1, E2, op, has_mask, mid_binarize,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((wp.shape[0], padded(out_n)), jnp.float32),
+        compiler_params=compiler_params(n_src, n_mid, out_n),
         interpret=interpret,
-    )(n_active1, block_idx1, n_active2, block_idx2, *operands)
+        name="gqfast_fused_hops",
+    )(cl1, cc1, *operands)
+    return out[:B, :out_n]
 
 
-_FUSED_STATICS = (
-    "n_mid", "n_dst", "op",
-    "dst1_width", "m1_mode", "m1_width",
-    "dst2_width", "m2_mode", "m2_width",
-    "mid_binarize", "interpret",
+@functools.partial(
+    jax.jit,
+    static_argnames=("n_mid", "n_dst", "cfg1_modes", "cfg2_modes", "op",
+                     "mid_binarize", "skip_chunks", "interpret"),
 )
-
-
-@functools.partial(jax.jit, static_argnames=_FUSED_STATICS)
-def fragment_spmv_fused(
-    weights: jnp.ndarray,  # f32[n_src] — hop1's incoming frontier
-    src1, dst1, m1, md1,  # hop1 streams (per cfg1 modes)
-    src2, dst2, m2, md2,  # hop2 streams; src2=None ⇒ degenerate 1-hop region
+def fragment_hops_fused(
+    weights: jnp.ndarray,  # f32[B, n_src] — hop1's incoming frontier
+    hop1: tuple,  # (src, dst, measure, mdict) streams per cfg1_modes
+    hop2: tuple | None,  # hop2 streams; None ⇒ degenerate 1-hop region
     mid_mask,  # f32[n_mid] ∧ of member filter masks | None
-    block_idx1, n_active1,  # hop1's prefetched block list
-    block_idx2, n_active2,  # hop2's list (ignored when degenerate)
     *,
-    n_mid: int, n_dst: int,
-    dst1_width: int = 0, m1_mode: str = "none", m1_width: int = 0,
-    dst2_width: int = 0, m2_mode: str = "none", m2_width: int = 0,
-    op: str = "sum", mid_binarize: bool = False, interpret: bool = False,
+    n_mid: int,
+    n_dst: int,
+    cfg1_modes: tuple,  # (src_width, dst_width, m_mode, m_width)
+    cfg2_modes: tuple | None = None,
+    op: str = "sum",
+    mid_binarize: bool = False,
+    skip_chunks: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
+    """Fused region over a ``[B, n_src]`` frontier → ``[B, n_dst]``."""
     return _fused_call(
-        weights, src1, dst1, m1, md1, src2, dst2, m2, md2, mid_mask,
-        block_idx1, n_active1, block_idx2, n_active2,
-        n_mid, n_dst,
-        (dst1_width, m1_mode, m1_width), (dst2_width, m2_mode, m2_width),
-        op, mid_binarize, interpret, batched=False,
-    )
-
-
-@functools.partial(jax.jit, static_argnames=_FUSED_STATICS)
-def fragment_spmm_fused(
-    weights: jnp.ndarray,  # f32[B, n_src] — the batched frontier matrix
-    src1, dst1, m1, md1,
-    src2, dst2, m2, md2,
-    mid_mask,
-    block_idx1, n_active1,
-    block_idx2, n_active2,
-    *,
-    n_mid: int, n_dst: int,
-    dst1_width: int = 0, m1_mode: str = "none", m1_width: int = 0,
-    dst2_width: int = 0, m2_mode: str = "none", m2_width: int = 0,
-    op: str = "sum", mid_binarize: bool = False, interpret: bool = False,
-) -> jnp.ndarray:
-    """Batched twin: the VMEM scratch holds ``[B, n_mid]`` and both phases use
-    the batched gather/scatter helpers — B queries share one fused pass."""
-    return _fused_call(
-        weights, src1, dst1, m1, md1, src2, dst2, m2, md2, mid_mask,
-        block_idx1, n_active1, block_idx2, n_active2,
-        n_mid, n_dst,
-        (dst1_width, m1_mode, m1_width), (dst2_width, m2_mode, m2_width),
-        op, mid_binarize, interpret, batched=True,
+        weights, hop1, hop2, mid_mask,
+        n_mid=n_mid, n_dst=n_dst, cfg1_modes=cfg1_modes, cfg2_modes=cfg2_modes,
+        op=op, mid_binarize=mid_binarize and hop2 is not None,
+        skip_chunks=skip_chunks, interpret=interpret,
     )

@@ -1,27 +1,27 @@
-"""Public jit'd wrappers for the Pallas kernels.
+"""Public entries for the Pallas kernels.
 
-On the CPU container the kernels execute via ``interpret=True`` (Pallas body run
-as Python/XLA — the correctness validation mode mandated for this environment);
-on TPU they run compiled. ``use_pallas=False`` selects the pure-XLA fallback
-(identical math from :mod:`repro.kernels.ref`).
+On a CPU backend the kernels execute via ``interpret=True`` (the Pallas body
+run as XLA ops — correctness only); on TPU they run compiled by Mosaic.
+``use_pallas=False`` selects the pure-XLA path (same math, from
+:mod:`repro.kernels.ref`), the degradation ladder's ``xla`` rung.
 
-Frontier-sparsity dispatch (kernels/active.py): the four hop entries accept
-``blocks=(src_min, src_max)`` per-block metadata and a ``block_skipping`` mode
-('off' | 'on' | 'auto'). With metadata present and skipping engaged, the call
-routes to the scalar-prefetch ``*_active`` kernel so only blocks whose src
-range intersects the frontier's support are streamed. Two tiers:
+Every hop entry (single/batched × dense/packed) lands in :func:`hop`, which
+runs :func:`repro.kernels.fragment_spmv.fragment_hop`; a single query is a
+batch of one. Two skipping mechanisms, both bit-identical to a full scan
+(skipped work contributes the ⊕-identity):
 
-  * **eager** (concrete frontier — kernel-level callers, benchmarks): the
-    active list is computed in numpy, the capacity bucketed to a power of two,
-    and the grid *really* shrinks; 'auto' bails back to the scan when the
-    surviving fraction exceeds ``SKIP_BLOCK_FRACTION``.
-  * **traced** (frontier is a jit tracer — the executor's compiled hop chain):
-    the list is computed in-graph at full capacity (static shapes), inactive
-    grid steps are ``pl.when``-guarded no-DMA no-ops; 'auto' wraps the choice
-    in a runtime ``lax.cond`` on the surviving-block count.
-
-Skipping is bit-identical to the scan for every op (skipped contributions are
-the ⊕-identity); the XLA fallback always full-scans, which is the same result.
+  * **frontier chunks** (always on unless ``block_skipping='off'``): the
+    kernel gathers only from 128-entry frontier chunks that hold a
+    non-identity value — the served path's skipping, since its edges are
+    destination-sorted;
+  * **edge blocks** (``blocks=(src_min, src_max)`` of source-sorted edges,
+    kernels/active.py): the scalar-prefetched block list drives the edge
+    streams' ``index_map`` so unreachable blocks are never DMA'd. Two tiers:
+    **eager** (concrete frontier — the list is computed in numpy, its
+    capacity bucketed to a power of two, and the grid really shrinks; 'auto'
+    bails back to the scan above ``SKIP_BLOCK_FRACTION``) and **traced**
+    (frontier is a jit tracer — full-capacity list, inactive steps do
+    nothing; 'auto' picks at runtime via ``lax.cond``).
 """
 from __future__ import annotations
 
@@ -40,21 +40,12 @@ from ..robust.errors import ValidationError
 from .bitmap_ops import bitmap_and as _bitmap_and
 from .bitmap_ops import bitmap_and_popcount as _bitmap_and_popcount
 from .bitunpack import bitunpack as _bitunpack
-from .fragment_spmm import fragment_spmm as _fragment_spmm
-from .fragment_spmm import fragment_spmm_active as _fragment_spmm_active
-from .fragment_spmm import fragment_spmm_packed as _fragment_spmm_packed
-from .fragment_spmm import fragment_spmm_packed_active as _fragment_spmm_packed_active
+from .fragment_spmv import GROUP_ROWS, n_edges, padded
 from .fragment_spmv import IDENTITY as _IDENTITY
-from .fragment_spmv import fragment_spmv as _fragment_spmv
-from .fragment_spmv import fragment_spmv_active as _fragment_spmv_active
-from .fragment_spmv_fused import _apply_mask as _fused_apply_mask
-from .fragment_spmv_fused import _binarize as _fused_binarize
-from .fragment_spmv_fused import fragment_spmm_fused as _fragment_spmm_fused
-from .fragment_spmv_fused import fragment_spmv_fused as _fragment_spmv_fused
-from .fragment_spmv_packed import fragment_spmv_packed as _fragment_spmv_packed
-from .fragment_spmv_packed import (
-    fragment_spmv_packed_active as _fragment_spmv_packed_active,
-)
+from .fragment_spmv import fragment_hop as _fragment_hop
+from .fragment_spmv_fused import apply_mask as _fused_apply_mask
+from .fragment_spmv_fused import binarize as _fused_binarize
+from .fragment_spmv_fused import fragment_hops_fused as _fragment_hops_fused
 from .params import FUSED_VMEM_BUDGET_BYTES
 
 BLOCK_SKIPPING_MODES = ("off", "on", "auto")
@@ -69,15 +60,19 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _plan_skip(w, op: str, E: int, blocks, block_skipping: str):
-    """Decide scan vs skip for one hop. ``None`` → full scan; otherwise
-    ``(block_idx, n_active, mode)`` with mode 'static' (commit to the active
-    kernel now) or 'cond' (traced 'auto': pick at runtime via lax.cond)."""
+def _check_skip_mode(block_skipping: str) -> None:
     if block_skipping not in BLOCK_SKIPPING_MODES:
         raise ValidationError(
             f"unknown block_skipping mode {block_skipping!r}",
             block_skipping=block_skipping, valid=BLOCK_SKIPPING_MODES,
         )
+
+
+def _plan_skip(w, op: str, E: int, blocks, block_skipping: str):
+    """Decide scan vs skip for one hop. ``None`` → full scan; otherwise
+    ``(block_idx, n_active, mode)`` with mode 'static' (commit to the active
+    kernel now) or 'cond' (traced 'auto': pick at runtime via lax.cond)."""
+    _check_skip_mode(block_skipping)
     if block_skipping == "off" or blocks is None or E == 0:
         return None
     nb = _active.n_edge_blocks(E)
@@ -129,9 +124,49 @@ def bitunpack(packed, width: int, count: int, use_pallas: bool = True):
     return _bitunpack(jnp.asarray(packed, jnp.uint32), width, count, interpret=_interpret())
 
 
+def _measure_operands(measure, mdict, m_mode: str):
+    if m_mode == "dense":
+        return jnp.asarray(measure, jnp.float32), None
+    if m_mode in ("packed", "dict"):
+        md = jnp.asarray(mdict, jnp.float32) if m_mode == "dict" else None
+        return jnp.asarray(measure, jnp.uint32), md
+    if m_mode != "none":
+        raise ValidationError(f"unknown measure mode {m_mode!r}", m_mode=m_mode)
+    return None, None
+
+
+def hop(weights, src, dst, measure=None, mdict=None, *, n_dst: int,
+        src_width: int = 0, dst_width: int = 0, m_mode: str = "none",
+        m_width: int = 0, op: str = "sum", blocks=None,
+        block_skipping: str = "off"):
+    """One hop through the Pallas kernel over a ``[B, n_src]`` frontier.
+    ``src``/``dst`` are dense int32 ids or BCA words (``*_width`` > 0), in
+    any edge order; ``blocks`` is the (src_min, src_max) skip metadata of
+    src-sorted edges, or None. Every public hop entry below lands here."""
+    w = jnp.asarray(weights, jnp.float32)
+    s = jnp.asarray(src, jnp.uint32 if src_width else jnp.int32)
+    d = jnp.asarray(dst, jnp.uint32 if dst_width else jnp.int32)
+    m, md = _measure_operands(measure, mdict, m_mode)
+    kw = dict(
+        n_dst=n_dst, src_width=src_width, dst_width=dst_width, m_mode=m_mode,
+        m_width=m_width, op=op, skip_chunks=block_skipping != "off",
+        interpret=_interpret(),
+    )
+    scan = lambda: _fragment_hop(w, s, d, m, md, None, None, **kw)
+    E = n_edges(s, src_width, d, dst_width)
+    plan = _plan_skip(w, op, E, blocks, block_skipping)
+    if plan is None:
+        return scan()
+    return _skip_or_cond(
+        plan, E, lambda bi, na: _fragment_hop(w, s, d, m, md, bi, na, **kw), scan,
+    )
+
+
 def fragment_spmv(weights, src_ids, dst_ids, measures, n_dst: int,
                   op: str = "sum", use_pallas: bool = True,
                   blocks=None, block_skipping: str = "off"):
+    """Single-query hop ``y[dst] ⊕= w[src] ⊗ m``: the batched hop with a
+    batch of one."""
     w = jnp.asarray(weights, jnp.float32)
     s = jnp.asarray(src_ids, jnp.int32)
     d = jnp.asarray(dst_ids, jnp.int32)
@@ -139,28 +174,18 @@ def fragment_spmv(weights, src_ids, dst_ids, measures, n_dst: int,
     if not use_pallas:
         return ref.fragment_spmv_ref(w, s, d, m, n_dst, op=op)
     _faults.fire("ops.fragment_spmv", op=op, n_dst=n_dst)
-    scan = lambda: _fragment_spmv(w, s, d, m, n_dst, op=op, interpret=_interpret())
-    plan = _plan_skip(w, op, s.shape[0], blocks, block_skipping)
-    if plan is None:
-        return scan()
-    return _skip_or_cond(
-        plan, s.shape[0],
-        lambda bi, na: _fragment_spmv_active(
-            w, s, d, m, bi, na, n_dst, op=op, interpret=_interpret()
-        ),
-        scan,
-    )
+    return hop(w[None], s, d, m, n_dst=n_dst, m_mode="dense", op=op,
+               blocks=blocks, block_skipping=block_skipping)[0]
 
 
 def fragment_spmm(weights, src_ids, dst_ids, measures, n_dst: int,
                   op: str = "sum", use_pallas: bool = True,
                   blocks=None, block_skipping: str = "off"):
     """Batched multi-query hop: ``Y[b, dst] ⊕= W[b, src] ⊗ m`` with one edge
-    stream serving all B frontier rows (see fragment_spmm.py). ``measures``
-    may be [E] (shared — the fused-kernel case) or [B, E] (per-row, e.g. a
-    seed-scalar-dependent measure expression): per-row streams have no
-    single-pass formulation and always take the XLA fallback, a vmap'd
-    segment-combine."""
+    stream serving all B frontier rows. ``measures`` may be [E] (shared) or
+    [B, E] (per-row, e.g. a seed-scalar-dependent measure expression):
+    per-row streams have no single-pass formulation and stay on XLA by
+    design, a vmap'd segment-combine (``explain()`` lists such hops)."""
     w = jnp.asarray(weights, jnp.float32)
     s = jnp.asarray(src_ids, jnp.int32)
     d = jnp.asarray(dst_ids, jnp.int32)
@@ -168,95 +193,56 @@ def fragment_spmm(weights, src_ids, dst_ids, measures, n_dst: int,
     if m.ndim == 2 or not use_pallas:
         return ref.fragment_spmm_ref(w, s, d, m, n_dst, op=op)
     _faults.fire("ops.fragment_spmm", op=op, n_dst=n_dst)
-    scan = lambda: _fragment_spmm(w, s, d, m, n_dst, op=op, interpret=_interpret())
-    plan = _plan_skip(w, op, s.shape[0], blocks, block_skipping)
-    if plan is None:
-        return scan()
-    return _skip_or_cond(
-        plan, s.shape[0],
-        lambda bi, na: _fragment_spmm_active(
-            w, s, d, m, bi, na, n_dst, op=op, interpret=_interpret()
-        ),
-        scan,
-    )
+    return hop(w, s, d, m, n_dst=n_dst, m_mode="dense", op=op,
+               blocks=blocks, block_skipping=block_skipping)
 
 
 def fragment_spmm_packed(weights, src_ids, dst, measure=None, mdict=None, *,
                          n_dst: int, dst_width: int = 0, m_mode: str = "none",
                          m_width: int = 0, op: str = "sum",
                          use_pallas: bool = True,
-                         blocks=None, block_skipping: str = "off"):
-    """Decode-fused batched hop: packed dst/measure word streams decode once
-    per 4096-edge block in VMEM and serve all B frontier rows."""
-    w = jnp.asarray(weights, jnp.float32)
-    s = jnp.asarray(src_ids, jnp.int32)
-    d = jnp.asarray(dst, jnp.uint32 if dst_width else jnp.int32)
-    m = measure
-    if m_mode == "dense":
-        m = jnp.asarray(m, jnp.float32)
-    elif m_mode in ("packed", "dict"):
-        m = jnp.asarray(m, jnp.uint32)
-    md = jnp.asarray(mdict, jnp.float32) if m_mode == "dict" else None
+                         blocks=None, block_skipping: str = "off",
+                         src_width: int = 0):
+    """Decode-fused batched hop: packed src/dst/measure word streams decode
+    once per 4096-edge block in VMEM and serve all B frontier rows."""
     if not use_pallas:
+        if src_width:
+            src_ids = ref.bitunpack_ref(
+                jnp.asarray(src_ids, jnp.uint32), src_width,
+                n_edges(src_ids, src_width, dst, dst_width),
+            )
         return ref.fragment_spmm_packed_ref(
-            w, s, d, m, md, n_dst, dst_width=dst_width,
-            m_mode=m_mode, m_width=m_width, op=op,
+            jnp.asarray(weights, jnp.float32), jnp.asarray(src_ids, jnp.int32),
+            jnp.asarray(dst, jnp.uint32 if dst_width else jnp.int32),
+            *_measure_operands(measure, mdict, m_mode), n_dst,
+            dst_width=dst_width, m_mode=m_mode, m_width=m_width, op=op,
         )
     _faults.fire("ops.fragment_spmm_packed", op=op, n_dst=n_dst)
-    scan = lambda: _fragment_spmm_packed(
-        w, s, d, m, md, n_dst, dst_width=dst_width,
-        m_mode=m_mode, m_width=m_width, op=op, interpret=_interpret(),
-    )
-    plan = _plan_skip(w, op, s.shape[0], blocks, block_skipping)
-    if plan is None:
-        return scan()
-    return _skip_or_cond(
-        plan, s.shape[0],
-        lambda bi, na: _fragment_spmm_packed_active(
-            w, s, d, m, md, bi, na, n_dst, dst_width=dst_width,
-            m_mode=m_mode, m_width=m_width, op=op, interpret=_interpret(),
-        ),
-        scan,
-    )
+    return hop(weights, src_ids, dst, measure, mdict, n_dst=n_dst,
+               src_width=src_width, dst_width=dst_width, m_mode=m_mode,
+               m_width=m_width, op=op, blocks=blocks,
+               block_skipping=block_skipping)
 
 
 def fragment_spmv_packed(weights, src_ids, dst, measure=None, mdict=None, *,
                          n_dst: int, dst_width: int = 0, m_mode: str = "none",
                          m_width: int = 0, op: str = "sum",
                          use_pallas: bool = True,
-                         blocks=None, block_skipping: str = "off"):
-    """Decode-fused hop: ``dst``/``measure`` may be BCA word streams that are
-    unpacked block-at-a-time inside the SpMV (see fragment_spmv_packed.py)."""
+                         blocks=None, block_skipping: str = "off",
+                         src_width: int = 0):
+    """Decode-fused single-query hop (the batched one with a batch of one)."""
     w = jnp.asarray(weights, jnp.float32)
-    s = jnp.asarray(src_ids, jnp.int32)
-    d = jnp.asarray(dst, jnp.uint32 if dst_width else jnp.int32)
-    m = measure
-    if m_mode == "dense":
-        m = jnp.asarray(m, jnp.float32)
-    elif m_mode in ("packed", "dict"):
-        m = jnp.asarray(m, jnp.uint32)
-    md = jnp.asarray(mdict, jnp.float32) if m_mode == "dict" else None
     if not use_pallas:
-        return ref.fragment_spmv_packed_ref(
-            w, s, d, m, md, n_dst, dst_width=dst_width,
-            m_mode=m_mode, m_width=m_width, op=op,
-        )
+        return fragment_spmm_packed(
+            w[None], src_ids, dst, measure, mdict, n_dst=n_dst,
+            dst_width=dst_width, m_mode=m_mode, m_width=m_width, op=op,
+            use_pallas=False, src_width=src_width,
+        )[0]
     _faults.fire("ops.fragment_spmv_packed", op=op, n_dst=n_dst)
-    scan = lambda: _fragment_spmv_packed(
-        w, s, d, m, md, n_dst, dst_width=dst_width,
-        m_mode=m_mode, m_width=m_width, op=op, interpret=_interpret(),
-    )
-    plan = _plan_skip(w, op, s.shape[0], blocks, block_skipping)
-    if plan is None:
-        return scan()
-    return _skip_or_cond(
-        plan, s.shape[0],
-        lambda bi, na: _fragment_spmv_packed_active(
-            w, s, d, m, md, bi, na, n_dst, dst_width=dst_width,
-            m_mode=m_mode, m_width=m_width, op=op, interpret=_interpret(),
-        ),
-        scan,
-    )
+    return hop(w[None], src_ids, dst, measure, mdict, n_dst=n_dst,
+               src_width=src_width, dst_width=dst_width, m_mode=m_mode,
+               m_width=m_width, op=op, blocks=blocks,
+               block_skipping=block_skipping)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -266,10 +252,9 @@ def fragment_spmv_packed(weights, src_ids, dst, measure=None, mdict=None, *,
 
 @dataclasses.dataclass(eq=False)
 class FusedHopOperands:
-    """One hop's streams for the fused entries. The frontier is *not* here:
-    hop1 reads the caller's ``weights``, hop2 reads the VMEM scratch. ``reach``
-    (hop2 only) is the fuse-time block reachability matrix ``bool[nb1, nb2]``
-    that derives hop2's active block list from hop1's."""
+    """One hop's streams for the fused entries, in any edge order (the
+    executor passes destination-sorted ones). The frontier is *not* here:
+    hop1 reads the caller's ``weights``, hop2 reads the VMEM scratch."""
 
     src_ids: Any
     dst: Any
@@ -279,27 +264,24 @@ class FusedHopOperands:
     dst_width: int = 0
     m_mode: str = "none"
     m_width: int = 0
-    blocks: Any = None  # (src_min, src_max) | None
-    reach: Any = None
+    src_width: int = 0
+
+    def streams(self):
+        """(src, dst, measure, mdict) coerced for the fused kernel."""
+        s = jnp.asarray(self.src_ids, jnp.uint32 if self.src_width else jnp.int32)
+        d = jnp.asarray(self.dst, jnp.uint32 if self.dst_width else jnp.int32)
+        return (s, d, *_measure_operands(self.measure, self.mdict, self.m_mode))
+
+    def modes(self) -> tuple:
+        return (self.src_width, self.dst_width, self.m_mode, self.m_width)
+
+    def n_edges(self) -> int:
+        return n_edges(self.src_ids, self.src_width, self.dst, self.dst_width)
 
 
-def _coerce_hop(h: FusedHopOperands):
-    s = jnp.asarray(h.src_ids, jnp.int32)
-    d = jnp.asarray(h.dst, jnp.uint32 if h.dst_width else jnp.int32)
-    m = h.measure
-    if h.m_mode == "dense":
-        m = jnp.asarray(m, jnp.float32)
-    elif h.m_mode in ("packed", "dict"):
-        m = jnp.asarray(m, jnp.uint32)
-    elif h.m_mode != "none":
-        raise ValidationError(
-            f"unknown measure mode {h.m_mode!r}", m_mode=h.m_mode,
-        )
-    md = jnp.asarray(h.mdict, jnp.float32) if h.m_mode == "dict" else None
-    return s, d, m, md
-
-
-def _fusion_unfusable(fusion: str, n_mid: int, batch: int) -> bool:
+def _fusion_unfusable(fusion: str, n_mid: int) -> bool:
+    """Whether a region runs unfused: 'off', or 'auto' when the resident
+    ``(8, n_mid)`` intermediate group exceeds FUSED_VMEM_BUDGET_BYTES."""
     if fusion not in FUSION_MODES:
         raise ValidationError(
             f"unknown fusion mode {fusion!r}", fusion=fusion, valid=FUSION_MODES,
@@ -308,88 +290,7 @@ def _fusion_unfusable(fusion: str, n_mid: int, batch: int) -> bool:
         return True
     if fusion == "on":
         return False
-    return 4 * n_mid * max(batch, 1) > FUSED_VMEM_BUDGET_BYTES
-
-
-def _full_blocks(nb: int):
-    return jnp.arange(nb, dtype=jnp.int32), jnp.asarray([nb], jnp.int32)
-
-
-def _np_block_list(flags: np.ndarray):
-    """Bucketed fixed-capacity list from eager flags (active.py layout)."""
-    act = np.flatnonzero(flags).astype(np.int32)
-    nb = int(flags.shape[0])
-    C = _active.bucket_capacity(int(act.shape[0]), nb)
-    idx = np.full(C, act[-1] if act.size else 0, np.int32)
-    idx[: act.shape[0]] = act
-    return jnp.asarray(idx), jnp.asarray([act.shape[0]], dtype=jnp.int32)
-
-
-def _fused_block_lists(w, op: str, h1, h2, E1: int, E2: int,
-                       block_skipping: str):
-    """The fused grid's two prefetched block lists. hop1's comes from the
-    incoming frontier's support exactly as in the unfused active kernels;
-    hop2's is derived WITHOUT reading the intermediate frontier, by OR-ing the
-    reach-matrix rows of hop1's active blocks (conservative superset → results
-    stay bit-identical). Skipping off/unavailable passes full arange lists —
-    one kernel body serves every mode."""
-    if block_skipping not in BLOCK_SKIPPING_MODES:
-        raise ValidationError(
-            f"unknown block_skipping mode {block_skipping!r}",
-            block_skipping=block_skipping, valid=BLOCK_SKIPPING_MODES,
-        )
-    nb1 = _active.n_edge_blocks(E1)
-    zero = _IDENTITY[op]
-    skip1 = (
-        block_skipping != "off" and h1.blocks is not None
-        and not (nb1 <= 1 and block_skipping != "on")
-    )
-    traced = isinstance(w, jax.core.Tracer)
-    flags1_t = flags1_np = None
-    if not skip1:
-        bi1, na1 = _full_blocks(nb1)
-    elif traced:
-        smin1, smax1 = h1.blocks
-        flags1_t = _active.active_flags(
-            _active.support_mask(w, zero), jnp.asarray(smin1), jnp.asarray(smax1)
-        )
-        bi1, na1 = _active.compact_blocks(flags1_t)
-        _obs_trace.annotate(skip_tier="traced", n_blocks=nb1)
-    else:
-        smin1, smax1 = h1.blocks
-        sup = np.asarray(_active.support_mask(w, zero)).astype(np.int64)
-        cs = np.concatenate([np.zeros(1, np.int64), np.cumsum(sup)])
-        flags1_np = cs[np.asarray(smax1) + 1] > cs[np.asarray(smin1)]
-        frac = flags1_np.sum() / nb1
-        if block_skipping == "auto" and frac > _active.SKIP_BLOCK_FRACTION:
-            bi1, na1 = _full_blocks(nb1)
-            flags1_np = None
-            _obs_trace.annotate(skip_tier="eager", skip_decision="scan",
-                                n_blocks=nb1)
-        else:
-            bi1, na1 = _np_block_list(flags1_np)
-            _obs_trace.annotate(
-                skip_tier="eager", skip_decision="skip", n_blocks=nb1,
-                active_blocks=int(flags1_np.sum()),
-                active_block_fraction=float(frac),
-            )
-    if h2 is None:
-        return bi1, na1, None, None
-    nb2 = _active.n_edge_blocks(E2)
-    reach = h2.reach
-    ok_reach = (
-        reach is not None
-        and tuple(np.asarray(reach).shape) == (nb1, nb2)
-    )
-    if ok_reach and flags1_t is not None:
-        flags2 = jnp.any(jnp.asarray(reach, bool) & flags1_t[:, None], axis=0)
-        bi2, na2 = _active.compact_blocks(flags2)
-    elif ok_reach and flags1_np is not None:
-        flags2 = np.asarray(reach, bool)[flags1_np].any(axis=0)
-        bi2, na2 = _np_block_list(flags2)
-    else:
-        bi2, na2 = _full_blocks(nb2)
-    return bi1, na1, bi2, na2
+    return 4 * GROUP_ROWS * padded(n_mid) > FUSED_VMEM_BUDGET_BYTES
 
 
 def _compose_unfused(packed_fn, weights, hop1, hop2, mid_mask,
@@ -402,7 +303,7 @@ def _compose_unfused(packed_fn, weights, hop1, hop2, mid_mask,
         weights, hop1.src_ids, hop1.dst, hop1.measure, hop1.mdict,
         n_dst=hop1.n_dst, dst_width=hop1.dst_width, m_mode=hop1.m_mode,
         m_width=hop1.m_width, op=op, use_pallas=use_pallas,
-        blocks=hop1.blocks, block_skipping=block_skipping,
+        block_skipping=block_skipping, src_width=hop1.src_width,
     )
     if mid_mask is not None:
         keep = mid_mask[None, :] if u.ndim == 2 else mid_mask
@@ -415,23 +316,23 @@ def _compose_unfused(packed_fn, weights, hop1, hop2, mid_mask,
         u, hop2.src_ids, hop2.dst, hop2.measure, hop2.mdict,
         n_dst=hop2.n_dst, dst_width=hop2.dst_width, m_mode=hop2.m_mode,
         m_width=hop2.m_width, op=op, use_pallas=use_pallas,
-        blocks=hop2.blocks, block_skipping=block_skipping,
+        block_skipping=block_skipping, src_width=hop2.src_width,
     )
 
 
 def _fused_dispatch(batched: bool, weights, hop1, hop2, mid_mask, *, op,
                     mid_binarize, use_pallas, fusion, block_skipping):
+    _check_skip_mode(block_skipping)
     w = jnp.asarray(weights, jnp.float32)
     mm = jnp.asarray(mid_mask, jnp.float32) if mid_mask is not None else None
-    E1 = hop1.src_ids.shape[0]
-    E2 = hop2.src_ids.shape[0] if hop2 is not None else 0
+    E1 = hop1.n_edges()
+    E2 = hop2.n_edges() if hop2 is not None else 0
     n_mid = hop1.n_dst
     n_dst = hop2.n_dst if hop2 is not None else hop1.n_dst
-    batch = w.shape[0] if batched else 1
     packed_fn = fragment_spmm_packed if batched else fragment_spmv_packed
     if (
         not use_pallas
-        or _fusion_unfusable(fusion, n_mid, batch)
+        or _fusion_unfusable(fusion, n_mid)
         or E1 == 0
         or (hop2 is not None and E2 == 0)
     ):
@@ -441,26 +342,16 @@ def _fused_dispatch(batched: bool, weights, hop1, hop2, mid_mask, *, op,
         )
     site = "ops.fragment_spmm_fused" if batched else "ops.fragment_spmv_fused"
     _faults.fire(site, op=op, n_dst=n_dst)
-    s1, d1, m1, md1 = _coerce_hop(hop1)
-    s2, d2, m2, md2 = _coerce_hop(hop2) if hop2 is not None else (None,) * 4
-    # plan from the caller's original frontier: a numpy frontier then plans
-    # entirely on the host, with no device round-trip for the support scan
-    bi1, na1, bi2, na2 = _fused_block_lists(
-        w if isinstance(w, jax.core.Tracer) else weights,
-        op, hop1, hop2, E1, E2, block_skipping
-    )
     _obs_trace.annotate(fused=True, fused_hops=2 if hop2 is not None else 1)
-    kern = _fragment_spmm_fused if batched else _fragment_spmv_fused
-    return kern(
-        w, s1, d1, m1, md1, s2, d2, m2, md2, mm, bi1, na1, bi2, na2,
-        n_mid=n_mid, n_dst=n_dst,
-        dst1_width=hop1.dst_width, m1_mode=hop1.m_mode, m1_width=hop1.m_width,
-        dst2_width=hop2.dst_width if hop2 is not None else 0,
-        m2_mode=hop2.m_mode if hop2 is not None else "none",
-        m2_width=hop2.m_width if hop2 is not None else 0,
+    out = _fragment_hops_fused(
+        w if batched else w[None],
+        hop1.streams(), hop2.streams() if hop2 is not None else None, mm,
+        n_mid=n_mid, n_dst=n_dst, cfg1_modes=hop1.modes(),
+        cfg2_modes=hop2.modes() if hop2 is not None else None,
         op=op, mid_binarize=mid_binarize and hop2 is not None,
-        interpret=_interpret(),
+        skip_chunks=block_skipping != "off", interpret=_interpret(),
     )
+    return out if batched else out[0]
 
 
 def fragment_spmv_fused(weights, hop1: FusedHopOperands,

@@ -1,34 +1,25 @@
 """Production meshes (importing this module never touches jax device state).
 
-Also the jax-version compatibility shim for mesh construction: ``axis_types``
-/ ``jax.sharding.AxisType`` only exist on newer jax; :func:`make_mesh` and
-:func:`_mesh_from_devices` request Auto axes when available and degrade to the
-plain constructor otherwise, so tests and benchmarks build meshes the same way
-everywhere."""
+Every mesh here has Auto axes, so tests, benchmarks and the engine build
+meshes the same way."""
 from __future__ import annotations
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """``jax.make_mesh(shape, axes, axis_types=Auto…)`` across jax versions."""
+    """``jax.make_mesh(shape, axes)`` with Auto axes."""
     import jax
 
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def _mesh_from_devices(devices, axes: tuple[str, ...]):
     import jax
 
-    try:
-        return jax.sharding.Mesh(
-            devices, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    except (AttributeError, TypeError):
-        return jax.sharding.Mesh(devices, axes)
+    return jax.sharding.Mesh(
+        devices, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -50,12 +50,21 @@ Durability surface (DESIGN.md §Durability):
     ``storage.materialize`` (healed by verified reads), ``scrub.verify``
     (detect → heal from snapshot), and ``snapshot.load`` (first hot-swap
     rolls back) — the corrupt-and-heal CI lane.
+
+Exit status: without ``--chaos`` the analytics server exits non-zero when a
+prepare or a warmup fails, when any request ends ``error`` or ``degraded``,
+or when ``--verify-responses`` finds a corrupt response — a run that hides a
+failure behind the degradation ladder is not a passing run. ``--docs`` sizes
+the PubMed deployment at the ratios of the paper's Table 1
+(:func:`repro.data.synth_graph.pubmed_table1_scale`).
 """
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 from collections import deque
+from pathlib import Path
 
 
 def _chaos_plan(seed: int, corrupt: bool = False):
@@ -122,7 +131,7 @@ def load_generation(snapshot_dir: str, queries: dict, sample_params,
     return eng, prepared, gen
 
 
-def _serve_analytics(args) -> None:
+def _serve_analytics(args) -> int:
     import contextlib
     import contextvars
     import json
@@ -165,9 +174,7 @@ def _serve_analytics(args) -> None:
                 print(f"  snapshot restore REJECTED [{e.code}]: {e}\n"
                       "  rebuilding from source data…")
     if db is None:
-        schema = SG.make_pubmed(
-            n_docs=args.docs, n_terms=1_200, n_authors=args.docs // 5, seed=5
-        )
+        schema = SG.pubmed_table1_scale(args.docs, seed=5)
         db = GQFastDatabase(schema, account_space=False)
         if args.snapshot_dir:
             from repro.storage.snapshot import latest_generation, snapshot_db
@@ -251,13 +258,28 @@ def _serve_analytics(args) -> None:
         old_handlers[signal.SIGHUP] = signal.signal(signal.SIGHUP, _on_hup)
 
     # response verification oracle: the pure-numpy reference engine has no
-    # fault sites, so its answers stay trustworthy while a chaos plan is live
-    if args.verify_responses:
-        from repro.core.reference import run_sql as _oracle_run_sql
+    # fault sites, so its answers stay trustworthy while a chaos plan is live;
+    # one engine per served schema (building it sorts every relation), with
+    # paths collapsed per hop so deployment-size AS/SD queries stay tractable
+    oracle: dict = {}
+
+    def _oracle(sdb, kind: str, params: dict):
+        from repro.core.planner import plan_query
+        from repro.core.reference import NumpyQueryEngine
+        from repro.core.sql import parse
+
+        if oracle.get("schema") is not sdb:
+            oracle.clear()
+            oracle.update(schema=sdb, eng=NumpyQueryEngine(sdb, collapse=True))
+        plans = oracle.setdefault("plans", {})
+        if kind not in plans:
+            plans[kind] = plan_query(sdb, parse(queries[kind]))
+        return oracle["eng"].execute_plan(plans[kind], params)
 
     results: list = []
     sizes: list[int] = []
     plan = None
+    failures: list[str] = []  # what makes a run without --chaos fail
     try:
         with chaos as plan:
             # prepare every shape; under chaos a prepare may eat an injected
@@ -277,6 +299,8 @@ def _serve_analytics(args) -> None:
             for name in list(prep_errors):
                 if name in prepared:
                     prep_errors.pop(name, None)
+            failures += [f"prepare {n} [{e.code}]: {e}"
+                         for n, e in prep_errors.items()]
 
             bucket = batch_bucket(args.batch)
 
@@ -403,6 +427,7 @@ def _serve_analytics(args) -> None:
                 except QueryError as e:  # chaos can fail a warmup compile;
                     reg.counter(f"robust.errors.{e.code}").inc()  # the ladder
                     # re-compiles per rung at serve time, so keep going
+                    failures.append(f"warmup {kind} [{e.code}]: {e}")
             print(f"  {time.time()-t0:.1f}s")
 
             if args.profile_json:
@@ -540,7 +565,7 @@ def _serve_analytics(args) -> None:
                         oc = outcomes[row]
                         if oc is None or oc.status == "error" or oc.value is None:
                             continue
-                        expect = _oracle_run_sql(sdb, queries[kind], pr)
+                        expect = _oracle(sdb, kind, pr)
                         reg.counter("serve.responses_verified").inc()
                         got = np.asarray(oc.value)
                         if got.shape != expect.shape or not np.allclose(
@@ -632,6 +657,16 @@ def _serve_analytics(args) -> None:
         print(f"  metrics written to {args.metrics_json}")
     if args.echo_metrics:
         print(json.dumps(reg.snapshot()["gauges"], indent=2))
+    if by_status["error"] or by_status["degraded"]:
+        failures.append(f"{by_status['error']} error and "
+                        f"{by_status['degraded']} degraded outcomes")
+    corrupt = reg.counter("serve.responses_corrupt").snapshot()
+    if corrupt:
+        failures.append(f"{corrupt} corrupt responses")
+    if failures and not args.chaos:
+        print("\nFAILED:\n  " + "\n  ".join(failures))
+        return 1
+    return 0
 
 
 def main() -> None:
@@ -643,7 +678,8 @@ def main() -> None:
                     help="analytics: max requests per micro-batch "
                          "(padded to the engine's bucket size)")
     ap.add_argument("--docs", type=int, default=20_000,
-                    help="analytics: synthetic database scale")
+                    help="analytics: documents of the PubMed deployment "
+                         "(Table 1 ratios)")
     ap.add_argument("--metrics-json", default=None, metavar="PATH",
                     help="analytics: dump the metrics registry (latency "
                          "histograms, occupancy/padding gauges, qps) as JSON")
@@ -687,15 +723,22 @@ def main() -> None:
                          "numpy oracle; count serve.responses_corrupt")
     args = ap.parse_args()
 
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    cache = enable_compile_cache(Path(__file__).resolve().parents[3])
+    devices = jax.devices()
+    print(f"device: platform {devices[0].platform}, kind "
+          f"{devices[0].device_kind}, count {len(devices)}; "
+          f"compile cache {cache}")
     if args.workload == "analytics":
         if args.requests is None:
             args.requests = 256
-        _serve_analytics(args)
-        return
+        sys.exit(_serve_analytics(args))
     if args.requests is None:
         args.requests = 60
 
-    import jax
     import jax.numpy as jnp
     import numpy as np
 

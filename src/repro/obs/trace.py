@@ -14,7 +14,7 @@ and :func:`annotate` is one ContextVar read plus a ``None`` check. Nothing in
 this module imports jax at module load; :meth:`Span.fence` imports it lazily.
 
 jit safety: spans record *around* traced calls, never inside them — the
-instrumented walker guards on ``jax.core.trace_state_clean()`` and degrades to
+instrumented walker guards on ``jax.core.trace_ctx.is_top_level()`` and degrades to
 a plain pass-through under any trace, so a recording tracer can stay enabled
 across ``jax.jit`` boundaries without corrupting timings or leaking tracers
 into host-side state.
@@ -51,7 +51,7 @@ class Span:
         """Block until the device work backing ``value`` completes and record
         the fenced duration since span entry as ``kernel_ms``. Returns
         ``value`` so call sites can fence inline. Never call on a jax tracer
-        (guard with ``trace_state_clean`` — see module docstring)."""
+        (guard with ``trace_ctx.is_top_level()`` — see module docstring)."""
         import jax
 
         jax.block_until_ready(value)

@@ -161,6 +161,33 @@ def test_mask_seed_and_seed_scalar_batched(dbs):
     _assert_batch_matches_loop(eng.prepare(SG.QUERY_FSD), 5, rng, {"d0": N_DOCS})
 
 
+def test_hop_without_reverse_index_runs_source_sorted_kernel(schema):
+    # a database holding only I_{DT.Doc}: the Doc→Term hop has no
+    # destination-sorted copy, so it runs the hop kernel over its own
+    # source-sorted edges (with block lists), single and batched
+    from repro.core.planner import plan_query
+    from repro.core.reference import NumpyQueryEngine
+    from repro.core.sql import parse
+
+    db = GQFastDatabase(schema, account_space=False)
+    del db.device.indexes[("DT", "Term")]
+    q = ("SELECT dt.Term, SUM(dt.Fre) FROM DT dt WHERE dt.Doc = :d0 "
+         "GROUP BY dt.Term")
+    pq = GQFastEngine(db).prepare(q)
+    route = [("DT.Doc->Term", "pallas (source-sorted)")]
+    assert pq.hop_routes() == route and pq.hop_routes(batched=True) == route
+    assert "hop DT.Doc->Term: pallas (source-sorted)" in pq.explain()
+    oracle = NumpyQueryEngine(schema)
+    plan = plan_query(schema, parse(q))
+    d0 = np.array([0, 5, 17])
+    got = pq.execute_batch(d0=d0)
+    for i, d in enumerate(d0):
+        want = oracle.execute_plan(plan, {"d0": int(d)})
+        assert (want != 0).any()
+        np.testing.assert_allclose(pq(d0=int(d)), want, rtol=1e-6)
+        np.testing.assert_array_equal(got[i], pq(d0=int(d)))
+
+
 def test_query_topk_batch(dbs):
     eng = GQFastEngine(dbs["packed"], strategy="frontier")
     ids = [3, 7, 11]

@@ -88,11 +88,7 @@ def test_sharded_embedding_lookup_8dev():
         sh = jnp.asarray(mod_shard_table(tbl, ns))
         ids = jnp.asarray(rng.integers(0, V, 64).astype(np.int32))
         sharded = jax.device_put(sh, jax.sharding.NamedSharding(mesh, P("model", None, None)))
-        try:
-            shard_map = jax.shard_map
-        except AttributeError:
-            from jax.experimental.shard_map import shard_map
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             lambda t, i: sharded_embedding_lookup(t.reshape(-1, D), i, ns),
             mesh=mesh, in_specs=(P("model", None, None), P()), out_specs=P()))
         out = np.asarray(f(sharded, ids))
@@ -120,11 +116,7 @@ def test_compressed_psum_8dev():
             m, er = compressed_psum(g[0], e[0], "data")
             return m, er[None]
 
-        try:
-            shard_map = jax.shard_map
-        except AttributeError:
-            from jax.experimental.shard_map import shard_map
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(P("data", None), P("data", None)),
             out_specs=(P(), P("data", None))))
 

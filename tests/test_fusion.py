@@ -1,7 +1,7 @@
 """Pipelined multi-hop fusion tests (DESIGN.md §Pipelined fusion).
 
 Covers the IR fusion pass (region formation boundaries, recursion into mask
-seeds, the unfuse inverse, the reach matrix), fused-vs-unfused/oracle
+seeds, the unfuse inverse), fused-vs-unfused/oracle
 bit-identity at the kernel level (dense/packed operands × every kernel op ×
 every skip mode × B=1/8), and the engine surface (every SQL aggregate,
 batched serving, the VMEM-budget auto fallback, explain/ladder integration).
@@ -15,7 +15,6 @@ import pytest
 from repro.core.engine import GQFastDatabase, GQFastEngine
 from repro.core.fragments import _pack_words
 from repro.core.fuse import (
-    _block_reach,
     fuse_plan,
     fusion_groups,
     has_fused,
@@ -76,7 +75,6 @@ def test_two_hop_chain_fuses_with_trailing_group():
     assert region.members == (h1, h2, p.ops[3])
     assert region.n_mid == h1.dom_dst
     assert region.hops == (h1, h2) and region.group is p.ops[3]
-    assert region.reach is not None and region.reach.dtype == bool
     assert "Fused[" in f.op_signature()[1]
     assert fusion_groups(f) and "Hop(" in fusion_groups(f)[0]
 
@@ -103,7 +101,7 @@ def test_one_hop_plus_mask_filter_fuses_degenerate():
     p = _mk_plan([_seed(), h1, filt, GroupOp("E2", 64)])
     f = fuse_plan(p)
     assert isinstance(f.ops[1], FusedHopOp)
-    assert f.ops[1].hops == (h1,) and f.ops[1].reach is None
+    assert f.ops[1].hops == (h1,) and f.ops[1].group is p.ops[3]
 
 
 def test_degree_filter_ends_region():
@@ -164,21 +162,6 @@ def test_unfuse_is_exact_inverse():
     assert u.ops == p.ops  # same member objects, same order
 
 
-def test_reach_matrix_matches_brute_force():
-    h1, h2 = _mk_hop(64, 9000, 6000, 7), _mk_hop(9000, 64, 2 * EDGE_BLOCK, 8)
-    reach = _block_reach(h1, h2)
-    dst1 = np.asarray(h1.dst_ids)
-    smin2, smax2 = np.asarray(h2.block_src_min), np.asarray(h2.block_src_max)
-    nb1, nb2 = reach.shape
-    assert nb1 == active.n_edge_blocks(dst1.shape[0])
-    assert nb2 == smin2.shape[0]
-    for b1 in range(nb1):
-        vals = dst1[b1 * EDGE_BLOCK:(b1 + 1) * EDGE_BLOCK]
-        for b2 in range(nb2):
-            want = bool(((vals >= smin2[b2]) & (vals <= smax2[b2])).any())
-            assert reach[b1, b2] == want, (b1, b2)
-
-
 # ---------------------------------------------------------------------------
 # Kernel level: fused vs unfused vs oracle, bit-identical
 # ---------------------------------------------------------------------------
@@ -204,14 +187,11 @@ def chain():
 
 def _operands(c, packed: bool):
     """(hop1, hop2, ref-kwargs) with dense or bit-packed dst/measure columns."""
-    b1 = active.block_ranges(c["src1"])
-    b2 = active.block_ranges(c["src2"])
-    reach = _reach_np(c["dst1"], *b2)
     if not packed:
         h1 = FusedHopOperands(c["src1"], c["dst1"], c["m1"], None, c["n1"],
-                              m_mode="dense", blocks=b1)
+                              m_mode="dense")
         h2 = FusedHopOperands(c["src2"], c["dst2"], c["m2"], None, c["n2"],
-                              m_mode="dense", blocks=b2, reach=reach)
+                              m_mode="dense")
         rk = dict(dst1_width=0, m1_mode="dense", m1_width=0,
                   dst2_width=0, m2_mode="dense", m2_width=0)
         return h1, h2, rk
@@ -220,27 +200,15 @@ def _operands(c, packed: bool):
     mw = 3  # measures are < 8
     h1 = FusedHopOperands(
         c["src1"], _pack_words(c["dst1"], w1), _pack_words(c["m1"].astype(np.int64), mw),
-        None, c["n1"], dst_width=w1, m_mode="packed", m_width=mw, blocks=b1,
+        None, c["n1"], dst_width=w1, m_mode="packed", m_width=mw,
     )
     h2 = FusedHopOperands(
         c["src2"], _pack_words(c["dst2"], w2), _pack_words(c["m2"].astype(np.int64), mw),
-        None, c["n2"], dst_width=w2, m_mode="packed", m_width=mw, blocks=b2,
-        reach=reach,
+        None, c["n2"], dst_width=w2, m_mode="packed", m_width=mw,
     )
     rk = dict(dst1_width=w1, m1_mode="packed", m1_width=mw,
               dst2_width=w2, m2_mode="packed", m2_width=mw)
     return h1, h2, rk
-
-
-def _reach_np(dst1, smin2, smax2):
-    nb1 = active.n_edge_blocks(dst1.shape[0])
-    smin2, smax2 = np.asarray(smin2), np.asarray(smax2)
-    reach = np.zeros((nb1, smin2.shape[0]), bool)
-    for b1 in range(nb1):
-        vals = dst1[b1 * EDGE_BLOCK:(b1 + 1) * EDGE_BLOCK]
-        reach[b1] = [((vals >= lo) & (vals <= hi)).any()
-                     for lo, hi in zip(smin2, smax2)]
-    return reach
 
 
 def _w(n, sl, op, B=None):
@@ -341,10 +309,10 @@ def test_fused_inside_jit_traced_tier(chain):
 
 def test_auto_fusion_respects_vmem_budget(chain, monkeypatch):
     c = chain
-    assert not ops._fusion_unfusable("auto", c["n1"], 1)
-    monkeypatch.setattr(ops, "FUSED_VMEM_BUDGET_BYTES", 4 * c["n1"] - 1)
-    assert ops._fusion_unfusable("auto", c["n1"], 1)
-    assert not ops._fusion_unfusable("on", c["n1"], 1)  # 'on' forces fused
+    assert not ops._fusion_unfusable("auto", c["n1"])
+    monkeypatch.setattr(ops, "FUSED_VMEM_BUDGET_BYTES", 4 * 8 * c["n1"] - 1)
+    assert ops._fusion_unfusable("auto", c["n1"])
+    assert not ops._fusion_unfusable("on", c["n1"])  # 'on' forces fused
     # over budget, auto degrades to the unfused composition — same bits
     h1, h2, rk = _operands(c, False)
     w = _w(c["n0"], slice(0, 24), "sum")
@@ -429,6 +397,20 @@ def test_engine_fused_batched_matches_unfused(engine):
     on = engine.prepare(q, fusion="on").execute_batch(d0=d0)
     off = engine.prepare(q, fusion="off").execute_batch(d0=d0)
     np.testing.assert_array_equal(on, off)
+
+
+def test_engine_auto_fuses_within_vmem_budget(engine):
+    # 'auto' forms the same regions as 'on' and runs them fused while the
+    # resident intermediate fits the VMEM budget: same plan, same bits
+    q = Q_SCORE.format(agg=AGG_CALLS["SUM"])
+    auto = engine.prepare(q)
+    assert auto.fusion == "auto" and has_fused(auto.phys)
+    assert fusion_groups(auto.phys) == fusion_groups(
+        engine.prepare(q, fusion="on").phys
+    )
+    np.testing.assert_array_equal(
+        auto(d0=7), engine.prepare(q, fusion="off")(d0=7)
+    )
 
 
 def test_engine_dense_encoding_fused(engine_dense):

@@ -34,6 +34,13 @@ from repro.robust import (
 from repro.robust import faults
 from repro.robust.runner import rung_fn
 
+# The xla rung sums the hop kernel's products by source-sorted segment sums;
+# the kernel sums destination-sorted 128-edge rows through exact bf16 splits
+# on the MXU. Same products, another order: the largest gap measured over
+# SD/FSD/AS/AD on four data seeds and four bindings each is 1.54e-7
+# relative (AS; ~1.3 f32 ulp), zeros agree exactly.
+XLA_RTOL = 1e-6
+
 
 @pytest.fixture(scope="module")
 def pubmed():
@@ -234,13 +241,16 @@ def test_ladder_rungs_agree_sd(engine, prepared_sd):
 
 
 def test_ladder_rungs_agree_float_measures(engine):
-    # float-measure chains: scan/xla are bit-identical (same ⊕ order);
-    # fragment_loop accumulates per-edge and agrees to float tolerance
+    # float-measure chains: the kernel rungs (active/unfused/scan) are
+    # bit-identical (same kernel, same ⊕ order); xla and fragment_loop sum
+    # the same products in another order and agree to float tolerance
     # (the documented bit-identity caveat, DESIGN.md §Robustness)
     p = engine.prepare(SG.QUERY_AS)
     want = p(a0=2)
-    for rung in ("scan", "xla"):
+    for rung in ("unfused", "scan"):
         assert np.array_equal(np.asarray(rung_fn(p, rung)(2)), want), rung
+    got = np.asarray(rung_fn(p, "xla")(2))
+    np.testing.assert_allclose(got, want, rtol=XLA_RTOL)
     got = np.asarray(rung_fn(p, "fragment_loop")(2))
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6)
 
@@ -416,8 +426,8 @@ def test_fused_kernel_fault_degrades_to_unfused(pubmed, engine):
         faults.FaultSpec(site="ops.fragment_spmv_fused", mode="raise")
     )
     with faults.active(plan):
-        # fusion='on': the pubmed reach matrix is dense, so 'auto' would
-        # decline the region and never reach the poisoned site
+        # fusion='on' runs the region fused whatever the VMEM budget says,
+        # so the poisoned site is reached
         pq = eng.prepare(SG.QUERY_AS, fusion="on")
         assert has_fused(pq.phys)  # the poisoned site is on the active path
         oc = run_with_policy(
@@ -484,7 +494,9 @@ def test_ladder_terminus_agrees_on_full_query_suite(engine):
         args = [params[n] for n in p.param_names]
         for rung in LADDER:
             got = np.asarray(rung_fn(p, rung)(*args))
-            if exact or rung != "fragment_loop":
+            if exact or rung not in ("xla", "fragment_loop"):
                 assert np.array_equal(got, want), (name, rung)
+            elif rung == "xla":
+                np.testing.assert_allclose(got, want, rtol=XLA_RTOL)
             else:
                 np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6)
