@@ -6,7 +6,8 @@
 (prepare once / execute many, as JDBC-style prepared statements)."""
 from __future__ import annotations
 
-from dataclasses import dataclass
+import hashlib
+from dataclasses import dataclass, field
 from typing import Any, Callable
 
 import numpy as np
@@ -110,6 +111,12 @@ def batch_bucket(b: int) -> int:
     return -(-b // BATCH_BUCKET_CAP) * BATCH_BUCKET_CAP
 
 
+def query_id(sql: str) -> str:
+    """Eight hex digits naming a query text (whitespace-insensitive)."""
+    text = " ".join(sql.split()).encode()
+    return hashlib.blake2s(text, digest_size=4).hexdigest()
+
+
 @dataclass
 class PreparedQuery:
     sql: str
@@ -132,6 +139,12 @@ class PreparedQuery:
     mesh: Any = None
     shard_axes: tuple = ("data",)
     sharded_db: Any = None
+    # short stable id of the query text: the ``query`` stat of the runner's
+    # ``batch`` span, so a trace names the shape behind each batch
+    query_id: str = field(init=False, default="")
+
+    def __post_init__(self):
+        self.query_id = query_id(self.sql)
 
     def validate_params(self, params: dict) -> None:
         """Typed parameter-binding validation: every declared parameter bound,
@@ -214,18 +227,7 @@ class PreparedQuery:
         hop's own index, where the database holds no reverse index — or
         ``xla: <reason>`` for hops that stay on XLA by design. The ladder can
         still demote a Pallas hop at run time; this is the plan's route."""
-        from .lower import (
-            HopOp, LBin, LCall, LParam, LSeedScalar, SeedOp, iter_flat_ops,
-        )
-
-        def batch_dependent(e) -> bool:
-            if isinstance(e, (LParam, LSeedScalar)):
-                return True
-            if isinstance(e, LBin):
-                return batch_dependent(e.left) or batch_dependent(e.right)
-            if isinstance(e, LCall):
-                return any(batch_dependent(a) for a in e.args)
-            return False
+        from .lower import HopOp, SeedOp, batch_dependent, iter_flat_ops
 
         def walk(phys):
             for op in iter_flat_ops(phys):

@@ -41,6 +41,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..kernels import active as active_meta
+from ..kernels.params import EDGE_BLOCK, LANES
 from ..obs import trace as obs_trace
 from ..robust.errors import ExecutionError, ValidationError
 from ..robust.runner import check_deadline
@@ -67,6 +68,7 @@ from .lower import (
     LParam,
     PhysicalPlan,
     SeedOp,
+    batch_dependent,
     eval_lexpr,
     iter_flat_ops,
     lower,
@@ -94,6 +96,12 @@ class DeviceIndex:
     # None (e.g. shard-built indexes) disables skipping for this index
     block_src_min: np.ndarray | None = None
     block_src_max: np.ndarray | None = None
+    # per 128-edge row [min, max] of the dst column in CSR order (host
+    # numpy, kernels/active.row_ranges): the source-chunk ranges the hop
+    # kernel walks when this index serves the opposite hop as its
+    # destination-sorted stream — the hop work counters' geometry
+    row_dst_min: np.ndarray | None = None
+    row_dst_max: np.ndarray | None = None
 
     @property
     def dst_ids(self) -> jnp.ndarray:
@@ -139,6 +147,7 @@ def build_device_db(
         )
         src = idx.src_ids()
         bmin, bmax = active_meta.block_ranges(src)
+        rmin, rmax = active_meta.row_ranges(cf.values)
         di = DeviceIndex(
             indptr=jnp.asarray(idx.indptr, dtype=jnp.int32),
             src_ids=jnp.asarray(src, dtype=jnp.int32),
@@ -146,6 +155,8 @@ def build_device_db(
             degrees=jnp.asarray(np.diff(idx.indptr), dtype=jnp.int32),
             block_src_min=bmin,
             block_src_max=bmax,
+            row_dst_min=rmin,
+            row_dst_max=rmax,
         )
         for m, cf in idx.columns.items():
             if m == other:
@@ -413,6 +424,104 @@ def execute_ir(phys: PhysicalPlan, make_interp) -> jnp.ndarray:
     return sr.finalize(walk_ir(phys, make_interp(sr, True)))
 
 
+@dataclass(frozen=True)
+class HopSlot:
+    """Static layout of one counted hop in an executable's counter array:
+    its label, batch groups, the frontier's chunk count, and how many
+    partial sums its gather trips take (0: gathers not counted)."""
+
+    label: str
+    groups: int
+    chunks: int
+    parts: int
+
+    @property
+    def size(self) -> int:
+        return 3 + 2 * self.parts  # executed, blocks, active chunks, sums
+
+
+@jax.tree_util.register_pytree_node_class
+class HopCounts:
+    """The hop work counters a batched executable returns beside its result:
+    one flat int32 array (device-resident until :meth:`decode` fetches it)
+    and the static :class:`HopSlot` layout, as a pytree whose aux data is
+    the layout."""
+
+    def __init__(self, values, slots: tuple):
+        self.values = values
+        self.slots = slots
+
+    def tree_flatten(self):
+        return (self.values,), self.slots
+
+    @classmethod
+    def tree_unflatten(cls, slots, children):
+        return cls(children[0], slots)
+
+    def decode(self) -> list[dict]:
+        """Fetch the counters: per counted hop in trace order, its ``label``,
+        ``rows`` streamed (blocks × 32 × batch groups), ``gathers`` (the
+        gather-loop trips of ``hop_block``; None where not counted) and the
+        frontier's ``active_chunks`` (None where not counted) of
+        ``chunks``. A hop the early exit skipped counts 0 rows and gathers."""
+        v = [int(x) for x in np.asarray(self.values)]
+        out, i = [], 0
+        for sl in self.slots:
+            executed, blocks, active = v[i:i + 3]
+            sums = v[i + 3:i + sl.size]
+            i += sl.size
+            rows = executed * blocks * (EDGE_BLOCK // LANES) * sl.groups
+            gathers = None
+            if sl.parts:
+                trips = sum(sums[:sl.parts]) + (sum(sums[sl.parts:]) << 16)
+                gathers = executed * sl.groups * trips
+            out.append(dict(label=sl.label, rows=rows, gathers=gathers,
+                            active_chunks=active if sl.parts else None,
+                            chunks=sl.chunks))
+        return out
+
+
+class HopLog:
+    """Numbering of an executable's hops (``h<k>`` in trace order, mask
+    sub-programs included) for the kernels' labels, and — when ``counting``
+    (the batched frontier executable) — the hop work counters, in-graph.
+    One log is shared by an interpreter and every interpreter it spawns."""
+
+    def __init__(self, counting: bool = False):
+        self.counting = counting
+        self.n = 0
+        self.slots: list[HopSlot] = []
+        self.values: list = []
+
+    def label(self, op: HopOp, layout: str) -> str:
+        k, self.n = self.n, self.n + 1
+        return f"h{k}:{op.table}.{op.src_key}->{op.dst_entity}:{layout}"
+
+    def add(self, label: str, w, live, blocks, flags=None, coverage=None,
+            chunks: int = 0) -> None:
+        """Count one hop run over frontier ``w``: ``blocks`` streamed edge
+        blocks; gather trips too when ``flags`` (its active chunks) and the
+        stream's ``coverage`` are given."""
+        from ..kernels.fragment_spmv import GROUP_ROWS, gather_trips, group_pad
+
+        head = [live.astype(jnp.int32), jnp.asarray(blocks, jnp.int32)]
+        parts = 0
+        if flags is not None and coverage is not None:
+            sums = gather_trips(flags, coverage)
+            parts = sums.shape[0] // 2
+            vec = jnp.concatenate([jnp.stack(head + [flags.sum(dtype=jnp.int32)]), sums])
+        else:
+            vec = jnp.stack(head + [jnp.int32(0)])
+        groups = group_pad(w.shape[0]) // GROUP_ROWS  # w is [B, n_src]
+        self.slots.append(HopSlot(label, groups, chunks, parts))
+        self.values.append(vec)
+
+    def counts(self) -> HopCounts:
+        vals = (jnp.concatenate(self.values) if self.values
+                else jnp.zeros((0,), jnp.int32))
+        return HopCounts(vals, tuple(self.slots))
+
+
 class _Interp:
     """Op dispatch + parameter/seed-scalar environment shared by strategies."""
 
@@ -503,17 +612,19 @@ class _FrontierInterp(_Interp):
 
     def __init__(self, params: dict[str, Any], sr: Semiring,
                  use_measures: bool = True, block_skipping: str = "auto",
-                 use_pallas: bool = True, fusion: str = "auto"):
+                 use_pallas: bool = True, fusion: str = "auto",
+                 hops: HopLog | None = None):
         super().__init__(params, sr, use_measures)
         self.block_skipping = block_skipping
         self.use_pallas = use_pallas
         self.fusion = fusion
+        self.hops = hops if hops is not None else HopLog()
 
     def spawn(self) -> "_FrontierInterp":
         """Interpreter for a mask sub-program (always the boolean semiring)."""
         return _FrontierInterp(
             self.params, BOOL_OR_AND, block_skipping=self.block_skipping,
-            use_pallas=self.use_pallas, fusion=self.fusion,
+            use_pallas=self.use_pallas, fusion=self.fusion, hops=self.hops,
         )
 
     def blocks_for(self, op: HopOp):
@@ -547,21 +658,68 @@ class _FrontierInterp(_Interp):
         sr, w = self.sr, state
         if op.semijoin:
             w = sr.binarize(w)
+        label = self.hops.label(op, "pull" if op.pull is not None else "src")
         if not self.early_exit:
-            return cont(self._hop_body(w, op))
+            return cont(self._hop_body(w, op, label))
         # all-zero frontier short-circuit: the hop's result is the ⊕-identity
         # accumulator whatever the index holds, so skip the kernel entirely —
         # in-trace, so multi-hop chains that die early stop scanning
+        empty = jnp.count_nonzero(w != sr.zero) == 0
+        if self.hops.counting:
+            self._count_hop(op, w, ~empty, label)
         out_shape = w.shape[:-1] + (op.dom_dst,)
         return cont(jax.lax.cond(
-            jnp.count_nonzero(w != sr.zero) == 0,
+            empty,
             lambda w: jnp.full(out_shape, sr.zero, jnp.float32),
-            lambda w: self._hop_body(w, op),
+            lambda w: self._hop_body(w, op, label),
             w,
         ))
 
-    def _hop_body(self, w, op: HopOp):
-        pulled = self.pull_hop(w, op)
+    def _kernel_stream(self, op: HopOp) -> str | None:
+        """Which stream the hop kernel reads for ``op`` in a batched
+        executable: ``pull`` (destination-sorted), ``src`` (source-sorted),
+        or None when the hop stays on XLA (the ``xla`` rung, a per-row
+        measure) — the static twin of ``pull_hop``/``_hop_body``'s routing."""
+        if not (self.use_pallas and self.kernel_hops):
+            return None
+        measure = op.pull.measure if op.pull is not None else op.measure
+        if self.use_measures and measure is not None and batch_dependent(measure):
+            return None
+        return "pull" if op.pull is not None else "src"
+
+    def _count_hop(self, op: HopOp, w, live, label: str) -> None:
+        """The hop's work counters, outside the early-exit cond: rows
+        streamed and, over the destination-sorted stream, the gather loop's
+        trips for this frontier's active chunks (kernels/fragment_spmv.py
+        ``hop_block``). Source-sorted hops count rows only."""
+        from ..kernels import ops as K
+        from ..kernels.fragment_spmv import n_chunks
+
+        kind = self._kernel_stream(op)
+        if kind == "pull":
+            self._count_pull(label, w, live, op.pull)
+        elif kind == "src" and op.src_ids.shape[0]:
+            E = int(op.src_ids.shape[0])
+            blocks = K.streamed_blocks(w, self.sr.name, E, self.blocks_for(op),
+                                       self.block_skipping)
+            self.hops.add(label, w, live, blocks, chunks=n_chunks(w.shape[-1]))
+
+    def _count_pull(self, label: str, w, live, pull) -> None:
+        """Counters of a hop over its destination-sorted stream ``pull``,
+        which streams every edge block."""
+        from ..kernels.fragment_spmv import chunk_flags, n_chunks
+
+        E = int(pull.dst_ids.shape[0])
+        if E:
+            n_src = w.shape[-1]
+            self.hops.add(
+                label, w, live, active_meta.n_edge_blocks(E),
+                chunk_flags(w, self.sr.name, self.block_skipping != "off"),
+                pull.coverage(n_src), chunks=n_chunks(n_src),
+            )
+
+    def _hop_body(self, w, op: HopOp, label: str = ""):
+        pulled = self.pull_hop(w, op, label)
         if pulled is not None:
             return pulled
         src, dst, valid = self.edge_arrays(op)
@@ -571,7 +729,7 @@ class _FrontierInterp(_Interp):
             m = jnp.broadcast_to(jnp.asarray(m, jnp.float32), (E,))
         else:
             m = jnp.ones(E, jnp.float32)
-        return self.spmv(w, src, dst, m, valid, op)
+        return self.spmv(w, src, dst, m, valid, op, label)
 
     def edge_arrays(self, op: HopOp):
         return op.src_ids, op.dst_ids, None
@@ -618,7 +776,7 @@ class _FrontierInterp(_Interp):
             m_mode=m_mode, m_width=m_width,
         )
 
-    def pull_hop(self, w, op: HopOp):
+    def pull_hop(self, w, op: HopOp, label: str = ""):
         """The hop kernel over the destination-sorted edges. Skipping there is
         by frontier chunk: only 128-entry chunks of ``w`` holding a
         non-identity value are gathered (``block_skipping`` 'off' reads every
@@ -630,16 +788,17 @@ class _FrontierInterp(_Interp):
             return None
         return K.fragment_spmv_packed(
             w, op=self.sr.name, use_pallas=True,
-            block_skipping=self.block_skipping, **kw,
+            block_skipping=self.block_skipping, label=label, **kw,
         )
 
-    def spmv(self, w, src, dst, m, valid, op: HopOp):
+    def spmv(self, w, src, dst, m, valid, op: HopOp, label: str = ""):
         from ..kernels import ops as K
 
         return K.fragment_spmv(
             w, src, dst, m, n_dst=op.dom_dst, op=self.sr.name,
             use_pallas=self.use_pallas,
             blocks=self.blocks_for(op), block_skipping=self.block_skipping,
+            label=label,
         )
 
     # -- pipelined fused regions (DESIGN.md §Pipelined fusion) --------------
@@ -678,13 +837,14 @@ class _FrontierInterp(_Interp):
         mid_binarize = bool(h2_op.semijoin) if h2_op is not None else False
         return h1_op, h2_op, hop1, hop2, mid_mask, mid_binarize
 
-    def _fused_call(self, w, hop1, hop2, mid_mask, mid_binarize):
+    def _fused_call(self, w, hop1, hop2, mid_mask, mid_binarize, labels):
         from ..kernels import ops as K
 
         return K.fragment_spmv_fused(
             w, hop1, hop2, mid_mask, op=self.sr.name,
             mid_binarize=mid_binarize, use_pallas=self.use_pallas,
             fusion=self.fusion, block_skipping=self.block_skipping,
+            labels=labels,
         )
 
     def fused_hop(self, op: FusedHopOp, state, cont):
@@ -698,21 +858,31 @@ class _FrontierInterp(_Interp):
         args = self._fused_region_args(op)
         if args is None:
             return super().fused_hop(op, state, cont)
+        from ..kernels import ops as K
+
         h1_op, h2_op, hop1, hop2, mid_mask, mid_binarize = args
         sr, w = self.sr, state
         if h1_op.semijoin:
             w = sr.binarize(w)
         n_out = hop2.n_dst if hop2 is not None else hop1.n_dst
+        # a fused hop2 gathers from the VMEM-resident intermediate
+        fused = K.fuses(hop1, hop2, self.fusion, self.use_pallas)
+        labels = (self.hops.label(h1_op, "pull"),)
+        if h2_op is not None:
+            labels += (self.hops.label(h2_op, "fused" if fused else "pull"),)
 
         def body(w):
-            return self._fused_call(w, hop1, hop2, mid_mask, mid_binarize)
+            return self._fused_call(w, hop1, hop2, mid_mask, mid_binarize, labels)
 
         if not self.early_exit:
             out = body(w)
         else:
             out_shape = w.shape[:-1] + (n_out,)
+            empty = jnp.count_nonzero(w != sr.zero) == 0
+            if self.hops.counting:
+                self._count_region(h1_op, h2_op, hop1, hop2, w, ~empty, labels)
             out = jax.lax.cond(
-                jnp.count_nonzero(w != sr.zero) == 0,
+                empty,
                 lambda w: jnp.full(out_shape, sr.zero, jnp.float32),
                 body, w,
             )
@@ -720,6 +890,19 @@ class _FrontierInterp(_Interp):
         if g is not None and g.entity is None:
             out = sr.to_mask(out)
         return cont(out)
+
+    def _count_region(self, h1_op, h2_op, hop1, hop2, w, live, labels) -> None:
+        """A fused region's counters: hop1 as a destination-sorted hop over
+        ``w``; hop2's rows only — its frontier is the intermediate, which
+        exists only inside the region (in VMEM, or inside the early-exit
+        cond when the region composes the unfused kernels)."""
+        from ..kernels.fragment_spmv import n_chunks
+
+        self._count_pull(labels[0], w, live, h1_op.pull)
+        if hop2 is not None and hop2.n_edges():
+            self.hops.add(labels[1], w, live,
+                          active_meta.n_edge_blocks(hop2.n_edges()),
+                          chunks=n_chunks(hop1.n_dst))
 
     def degree_filter(self, op: DegreeFilterOp, state, cont):
         return cont(self.sr.mask(state, self.degrees(op) > 0))
@@ -751,42 +934,70 @@ class hoisted_jit:
     as constants: at deployment size that is hundreds of MB per executable,
     copied into host memory and through the compiler for every prepared
     shape. Traces once per argument signature; ``lower`` mirrors
-    ``jax.jit(...).lower`` for executable inspection."""
+    ``jax.jit(...).lower`` for executable inspection.
 
-    def __init__(self, fn):
+    The executable is named ``name`` (the XLA module ``jit_<name>``). With
+    ``counts``, ``fn`` returns ``(result, HopCounts)``: calling the object
+    returns the result alone, :meth:`counted` both. Building an entry —
+    tracing it and its first call, which compiles — runs in a ``compile``
+    span (``obs.trace``)."""
+
+    def __init__(self, fn, name: str = "gqfast_executable", counts: bool = False):
         self.fn = fn
+        self.name = name
+        self.counts = counts
         self._entries: dict = {}
 
     def _entry(self, args):
         key = tuple(jax.typeof(a) for a in args)
         if key not in self._entries:
-            closed = jax.make_jaxpr(self.fn)(*args)
+            closed, shape = jax.make_jaxpr(self.fn, return_shape=True)(*args)
+            tree = jax.tree.structure(shape)
             consts = [jnp.asarray(c) if isinstance(c, np.ndarray) else c
                       for c in closed.consts]
-            run = jax.jit(
-                lambda consts, *a: jax.core.eval_jaxpr(closed.jaxpr, consts, *a)[0]
-            )
-            self._entries[key] = (run, consts)
+
+            def run(consts, *a):
+                return jax.tree.unflatten(
+                    tree, jax.core.eval_jaxpr(closed.jaxpr, consts, *a)
+                )
+
+            run.__name__ = run.__qualname__ = self.name
+            self._entries[key] = [jax.jit(run), consts, False]
         return self._entries[key]
 
+    def _run(self, args):
+        entry = self._entries.get(tuple(jax.typeof(a) for a in args))
+        if entry is not None and entry[2]:
+            return entry[0](entry[1], *args)
+        with obs_trace.span("compile", executable=self.name):
+            entry = self._entry(args)
+            out = entry[0](entry[1], *args)  # lowers and compiles, then runs
+        entry[2] = True
+        return out
+
     def __call__(self, *args):
-        run, consts = self._entry(args)
-        return run(consts, *args)
+        out = self._run(args)
+        return out[0] if self.counts else out
+
+    def counted(self, *args):
+        """``(result, HopCounts | None)`` from one execution."""
+        out = self._run(args)
+        return tuple(out) if self.counts else (out, None)
 
     def lower(self, *args):
-        run, consts = self._entry(args)
+        run, consts, _ = self._entry(args)
         return run.lower(consts, *args)
 
 
 def compile_frontier(
     db: DeviceDB, plan: ChainPlan | PhysicalPlan,
     block_skipping: str = "auto", use_pallas: bool = True,
-    fusion: str = "auto",
+    fusion: str = "auto", name: str = "gqfast_frontier",
 ) -> Callable[..., jnp.ndarray]:
     phys = ensure_lowered(db, plan)
     names = list(phys.param_names)
 
-    @hoisted_jit
+    @functools.partial(hoisted_jit, name=name)
     def run(*args):
         params = dict(zip(names, args))
         return execute_ir(
@@ -824,26 +1035,27 @@ class _BatchedFrontierInterp(_FrontierInterp):
     def __init__(self, params: dict[str, Any], sr: Semiring,
                  use_measures: bool = True, *, batch: int,
                  block_skipping: str = "auto", use_pallas: bool = True,
-                 fusion: str = "auto"):
+                 fusion: str = "auto", hops: HopLog | None = None):
         super().__init__(params, sr, use_measures,
                          block_skipping=block_skipping, use_pallas=use_pallas,
-                         fusion=fusion)
+                         fusion=fusion, hops=hops)
         self.batch = batch
 
     def spawn(self) -> "_BatchedFrontierInterp":
         return _BatchedFrontierInterp(
             self.params, BOOL_OR_AND, batch=self.batch,
             block_skipping=self.block_skipping, use_pallas=self.use_pallas,
-            fusion=self.fusion,
+            fusion=self.fusion, hops=self.hops,
         )
 
-    def _fused_call(self, w, hop1, hop2, mid_mask, mid_binarize):
+    def _fused_call(self, w, hop1, hop2, mid_mask, mid_binarize, labels):
         from ..kernels import ops as K
 
         return K.fragment_spmm_fused(
             w, hop1, hop2, mid_mask, op=self.sr.name,
             mid_binarize=mid_binarize, use_pallas=self.use_pallas,
             fusion=self.fusion, block_skipping=self.block_skipping,
+            labels=labels,
         )
 
     def _seed_ids(self, i) -> jnp.ndarray:
@@ -881,13 +1093,13 @@ class _BatchedFrontierInterp(_FrontierInterp):
             m = m * c.mask(self.params, self.attr_col).astype(jnp.float32)
         return cont(sr.from_mask(m))
 
-    def _hop_body(self, w, op: HopOp):
+    def _hop_body(self, w, op: HopOp, label: str = ""):
         # the [B, n_src] frontier reaches the kernel dispatch whole: the
         # active-chunk list is the union of per-row supports, so one SMEM
         # list serves the entire batch
         from ..kernels import ops as K
 
-        pulled = self.pull_hop(w, op)
+        pulled = self.pull_hop(w, op, label)
         if pulled is not None:
             return pulled
         src, dst = op.src_ids, op.dst_ids
@@ -907,9 +1119,10 @@ class _BatchedFrontierInterp(_FrontierInterp):
             w, src, dst, m, n_dst=op.dom_dst, op=self.sr.name,
             use_pallas=self.use_pallas,
             blocks=self.blocks_for(op), block_skipping=self.block_skipping,
+            label=label,
         )
 
-    def pull_hop(self, w, op: HopOp):
+    def pull_hop(self, w, op: HopOp, label: str = ""):
         from ..kernels import ops as K
 
         kw = self._pull_operands(op)
@@ -917,20 +1130,23 @@ class _BatchedFrontierInterp(_FrontierInterp):
             return None
         return K.fragment_spmm_packed(
             w, op=self.sr.name, use_pallas=True,
-            block_skipping=self.block_skipping, **kw,
+            block_skipping=self.block_skipping, label=label, **kw,
         )
 
 
 def compile_frontier_batched(
     db: DeviceDB, plan: ChainPlan | PhysicalPlan,
     block_skipping: str = "auto", use_pallas: bool = True,
-    fusion: str = "auto",
+    fusion: str = "auto", name: str = "gqfast_frontier_batched",
 ) -> Callable[..., jnp.ndarray]:
     """Batched serving entry: takes one [B] array per query parameter and
     returns the [B, out_dom] result block in one traced pass — every HopOp
     runs as a fused SpMM streaming the edge arrays once for the whole batch.
     Each distinct B compiles once; callers bound recompiles by padding ragged
-    batches to bucket sizes (engine.PreparedQuery.execute_batch)."""
+    batches to bucket sizes (engine.PreparedQuery.execute_batch). The same
+    executable also computes the hop work counters (:class:`HopCounts`),
+    which ``counted`` returns beside the result and which stay on the
+    device unless fetched."""
     phys = ensure_lowered(db, plan)
     names = list(phys.param_names)
     if not names:
@@ -938,17 +1154,19 @@ def compile_frontier_batched(
             "batched execution needs at least one query parameter"
         )
 
-    @hoisted_jit
+    @functools.partial(hoisted_jit, name=name, counts=True)
     def run(*args):
         B = args[0].shape[0]
         params = {n: jnp.asarray(a)[:, None] for n, a in zip(names, args)}
-        return execute_ir(
+        log = HopLog(counting=True)
+        out = execute_ir(
             phys,
             lambda sr, um: _BatchedFrontierInterp(
                 params, sr, um, batch=B, block_skipping=block_skipping,
-                use_pallas=use_pallas, fusion=fusion,
+                use_pallas=use_pallas, fusion=fusion, hops=log,
             ),
         )
+        return out, log.counts()
 
     return run
 
@@ -1023,6 +1241,7 @@ class _FragmentLoopInterp(_Interp):
 def compile_fragment_loop(
     db: DeviceDB, plan: ChainPlan | PhysicalPlan,
     block_skipping: str = "auto", use_pallas: bool = True,
+    name: str = "gqfast_fragment_loop",
 ) -> Callable[..., jnp.ndarray]:
     """Nested fori_loops over fragments, scalar per-edge accumulator updates.
     Only id-seeded chains (SD/FSD/AS shapes); mask seeds and semijoins fall
@@ -1034,11 +1253,11 @@ def compile_fragment_loop(
         isinstance(op, HopOp) and op.semijoin for op in iter_flat_ops(phys)
     ):
         return compile_frontier(db, phys, block_skipping=block_skipping,
-                                use_pallas=use_pallas)
+                                use_pallas=use_pallas, name=name)
     phys = densify_plan(phys)  # scalar loops have no packed path (§Storage)
     names = list(phys.param_names)
 
-    @hoisted_jit
+    @functools.partial(hoisted_jit, name=name)
     def run(*args):
         params = dict(zip(names, args))
         return execute_ir(
@@ -1137,7 +1356,7 @@ class _DistributedInterp(_FrontierInterp):
     def degrees(self, op: DegreeFilterOp):
         return self.side[f"deg::{op.table}::{op.src_key}"]
 
-    def spmv(self, w, src, dst, m, valid, op: HopOp):
+    def spmv(self, w, src, dst, m, valid, op: HopOp, label: str = ""):
         sr = self.sr
         ew = sr.mask(sr.extend(jnp.take(w, src), m), valid)
         part = sr.segment(ew, dst, op.dom_dst)

@@ -109,6 +109,19 @@ class LCall:
 LExpr = Union[LConst, LParam, LCol, LSeedScalar, LBin, LCall]
 
 
+def batch_dependent(e) -> bool:
+    """Whether an expression differs per batch row (it reads a parameter or
+    a seed scalar): such a measure has no shared edge stream, so a batched
+    hop over it stays on XLA."""
+    if isinstance(e, (LParam, LSeedScalar)):
+        return True
+    if isinstance(e, LBin):
+        return batch_dependent(e.left) or batch_dependent(e.right)
+    if isinstance(e, LCall):
+        return any(batch_dependent(a) for a in e.args)
+    return False
+
+
 def eval_lexpr(e: LExpr, params: dict, scalars: dict, col):
     """Evaluate a lowered expression. ``col(LCol)`` supplies the column values
     (whole array for vector strategies, one element for the scalar strategy);
@@ -219,6 +232,24 @@ class PullBinding:
     dst_ids: Any  # int32[E], sorted
     src_col: Any  # repro.storage.DeviceColumn
     measure: LExpr | None = None
+    # per 128-edge row [src_min, src_max] of src_col (the reverse index's
+    # DeviceIndex.row_dst_*; host numpy) — the hop work counters' geometry
+    row_src_min: Any = None
+    row_src_max: Any = None
+    _coverage: dict = field(default_factory=dict, repr=False)
+
+    def coverage(self, n_src: int):
+        """``kernels.active.chunk_coverage`` of this stream for an
+        ``n_src``-wide frontier (cached), or None without row ranges."""
+        if self.row_src_min is None:
+            return None
+        if n_src not in self._coverage:
+            from ..kernels.active import chunk_coverage
+
+            self._coverage[n_src] = chunk_coverage(
+                self.row_src_min, self.row_src_max, int(self.dst_ids.shape[0]), n_src
+            )
+        return self._coverage[n_src]
 
 
 @dataclass(eq=False)
@@ -445,7 +476,9 @@ def _pull_binding(db, table: str, src_key: str, measure) -> PullBinding | None:
         return e
 
     return PullBinding(
-        rev.src_ids, rev.dst_col, rebind(measure) if measure is not None else None
+        rev.src_ids, rev.dst_col, rebind(measure) if measure is not None else None,
+        row_src_min=getattr(rev, "row_dst_min", None),
+        row_src_max=getattr(rev, "row_dst_max", None),
     )
 
 

@@ -24,6 +24,10 @@ chunk instead — see :mod:`.ops`):
     bucketed to a power of two, so the grid itself shrinks to the surviving
     blocks and recompiles stay bounded at ~log2(n_blocks) per shape.
 
+The same module holds the geometry behind the hop kernel's work counters
+(:func:`row_ranges`, :func:`chunk_coverage`): per 128-edge row of a stream,
+the range of source chunks its inner gather loop walks.
+
 Skipping is *bit-identical* to the full scan for every combine op: a skipped
 block's sources all carry the ⊕-identity, so its per-block contribution is the
 ⊕-identity vector and ``combine(acc, identity) == acc`` exactly (0 for sum,
@@ -36,7 +40,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from .params import EDGE_BLOCK
+from .params import EDGE_BLOCK, LANES
 
 #: Runtime "auto" heuristic: engage skipping only while the surviving-block
 #: fraction is at most this — above it the scan's simpler schedule wins and
@@ -126,3 +130,37 @@ def active_block_list_np(support, src_min, src_max):
     idx[: act.shape[0]] = act
     n_active = np.asarray([act.shape[0]], np.int32)
     return idx, n_active, act.shape[0] / nb
+
+
+def row_ranges(src_ids) -> tuple[np.ndarray, np.ndarray]:
+    """Per 128-edge row ``[src_min, src_max]`` of an edge stream in the
+    order the hop kernel reads it (host/numpy, once at ``build_device_db``
+    time, like :func:`block_ranges`). A last, partial row covers its real
+    edges only; :func:`chunk_coverage` adds the padding."""
+    src = np.asarray(src_ids)
+    if src.shape[0] == 0:
+        return np.zeros(0, np.int32), np.zeros(0, np.int32)
+    starts = np.arange(0, src.shape[0], LANES)
+    return (np.minimum.reduceat(src, starts).astype(np.int32),
+            np.maximum.reduceat(src, starts).astype(np.int32))
+
+
+def chunk_coverage(row_min, row_max, E: int, n_src: int) -> np.ndarray:
+    """int64[n_src // 128 + 1]: for each 128-lane frontier chunk ``c``, how
+    many streamed rows of the hop kernel have ``c`` inside their source-chunk
+    range ``[min(s) >> 7, max(s) >> 7]`` — every row of every EDGE_BLOCK
+    block, padding rows included (their sources read ``n_src``, the spare
+    chunk). The kernel's gather loop runs, per row and batch group, once per
+    *active* chunk of that range, so its trip count for a frontier with
+    active-chunk flags ``f`` is ``groups · Σ_c f[c] · coverage[c]``."""
+    nc = n_src // LANES + 1
+    rows = n_edge_blocks(E) * (EDGE_BLOCK // LANES)
+    lo = np.full(rows, n_src >> 7, np.int64)
+    hi = np.full(rows, n_src >> 7, np.int64)
+    real = np.asarray(row_min).shape[0]
+    lo[:real] = np.asarray(row_min) >> 7
+    hi[:real] = np.asarray(row_max) >> 7
+    if real and E % LANES:
+        hi[real - 1] = n_src >> 7  # the last row's padding lanes
+    diff = np.bincount(lo, minlength=nc + 1) - np.bincount(hi + 1, minlength=nc + 1)
+    return np.cumsum(diff[:nc])

@@ -50,12 +50,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .params import EDGE_BLOCK, VMEM_LIMIT_BYTES
+from .params import EDGE_BLOCK, LANES, VMEM_LIMIT_BYTES
 
-LANES = 128
 GROUP_ROWS = 8  # frontier rows per batch group: one sublane tile
 ROWS_PER_BLOCK = EDGE_BLOCK // LANES
 
@@ -125,6 +125,41 @@ def chunk_lists(w_padded, op: str, skip: bool):
         [jnp.zeros(1, jnp.int32), jnp.cumsum(flags.astype(jnp.int32))]
     )
     return order, cnt
+
+
+#: Chunks summed per partial sum of :func:`gather_trips`: with 16-bit halves
+#: of the coverage, every partial sum stays below 2**31.
+TRIP_SEGMENT = 1 << 15
+
+
+def chunk_flags(w, op: str, skip: bool):
+    """bool[n_chunks(n_src)]: the chunks :func:`chunk_lists` marks active,
+    from the unpadded ``[B, n_src]`` frontier (the spare chunk holds only
+    ⊕-identity padding, so it is active only when ``skip`` is off)."""
+    n_src = w.shape[-1]
+    nc = n_chunks(n_src)
+    if not skip:
+        return jnp.ones((nc,), bool)
+    nz = jnp.pad((w != IDENTITY[op]).any(axis=0), (0, nc * LANES - n_src))
+    return nz.reshape(nc, LANES).any(axis=1)
+
+
+def gather_trips(flags, coverage) -> jnp.ndarray:
+    """``Σ_c flags[c] · coverage[c]`` — the gather-loop trips of
+    :func:`hop_block` per batch group, given the stream's
+    ``kernels.active.chunk_coverage`` — as exact int32 partial sums: the
+    coverage splits into 16-bit halves and the chunks into segments of
+    :data:`TRIP_SEGMENT`, so ``int32[2·S]`` holds S low sums then S high
+    sums, and the count is ``Σ low + 2**16 · Σ high``."""
+    nc = flags.shape[0]
+    S = -(-nc // TRIP_SEGMENT)
+    cov = np.zeros(S * TRIP_SEGMENT, np.int32)  # a row count: below 2**31
+    cov[:nc] = coverage
+    cov = jnp.asarray(cov.reshape(S, TRIP_SEGMENT))
+    f = jnp.pad(flags.astype(jnp.int32), (0, S * TRIP_SEGMENT - nc))
+    f = f.reshape(S, TRIP_SEGMENT)
+    return jnp.concatenate([(f * (cov & 0xFFFF)).sum(axis=1),
+                            (f * (cov >> 16)).sum(axis=1)])
 
 
 def _edge_rows(x, n_blocks: int, fill, dtype):
@@ -442,7 +477,7 @@ def _hop_kernel(cfg: HopCfg, n_edges: int, op: str, *refs):
 @functools.partial(
     jax.jit,
     static_argnames=("n_dst", "src_width", "dst_width", "m_mode", "m_width",
-                     "op", "skip_chunks", "interpret"),
+                     "op", "skip_chunks", "interpret", "label"),
 )
 def fragment_hop(
     weights: jnp.ndarray,  # f32[B, n_src]
@@ -461,11 +496,14 @@ def fragment_hop(
     op: str = "sum",
     skip_chunks: bool = True,
     interpret: bool = False,
+    label: str = "",
 ) -> jnp.ndarray:
     """The hop over ``E`` edges given in any order (``src``/``dst`` dense
     ids or BCA words). ``block_idx``/``n_active`` select edge blocks
     (kernels/active.py layout); ``None`` streams every block. Returns
-    ``[B, n_dst]``."""
+    ``[B, n_dst]``. A ``label`` (the executor's hop) goes into the kernel's
+    ``kernel_metadata`` as ``{"hop": label}``, where a device trace shows it
+    beside the unchanged instruction name ``gqfast_hop``."""
     if op not in IDENTITY:
         raise ValueError(f"unknown combine op {op!r}")
     B, n_src = weights.shape
@@ -500,6 +538,7 @@ def fragment_hop(
         compiler_params=compiler_params(n_src, n_dst),
         interpret=interpret,
         name="gqfast_hop",
+        metadata={"hop": label} if label else None,
     )(n_active, block_idx, clist, ccnt, wp, *operands)
     return out[:B, :n_dst]
 

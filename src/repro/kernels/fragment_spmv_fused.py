@@ -126,7 +126,7 @@ def _fused_kernel(C1, cfg1, cfg2, E1, E2, op, has_mask, mid_binarize, *refs):
 def _fused_call(
     weights, hop1, hop2, mid_mask, *,
     n_mid, n_dst, cfg1_modes, cfg2_modes, op, mid_binarize, skip_chunks,
-    interpret,
+    interpret, label,
 ):
     if op not in IDENTITY:
         raise ValueError(f"unknown combine op {op!r}")
@@ -191,6 +191,7 @@ def _fused_call(
         compiler_params=compiler_params(n_src, n_mid, out_n),
         interpret=interpret,
         name="gqfast_fused_hops",
+        metadata={"hop": label} if label else None,
     )(cl1, cc1, *operands)
     return out[:B, :out_n]
 
@@ -198,7 +199,7 @@ def _fused_call(
 @functools.partial(
     jax.jit,
     static_argnames=("n_mid", "n_dst", "cfg1_modes", "cfg2_modes", "op",
-                     "mid_binarize", "skip_chunks", "interpret"),
+                     "mid_binarize", "skip_chunks", "interpret", "label"),
 )
 def fragment_hops_fused(
     weights: jnp.ndarray,  # f32[B, n_src] — hop1's incoming frontier
@@ -214,11 +215,14 @@ def fragment_hops_fused(
     mid_binarize: bool = False,
     skip_chunks: bool = True,
     interpret: bool = False,
+    label: str = "",
 ) -> jnp.ndarray:
-    """Fused region over a ``[B, n_src]`` frontier → ``[B, n_dst]``."""
+    """Fused region over a ``[B, n_src]`` frontier → ``[B, n_dst]``. A
+    ``label`` (both member hops, ``+``-joined) goes into the kernel's
+    ``kernel_metadata`` as ``{"hop": label}``."""
     return _fused_call(
         weights, hop1, hop2, mid_mask,
         n_mid=n_mid, n_dst=n_dst, cfg1_modes=cfg1_modes, cfg2_modes=cfg2_modes,
         op=op, mid_binarize=mid_binarize and hop2 is not None,
-        skip_chunks=skip_chunks, interpret=interpret,
+        skip_chunks=skip_chunks, interpret=interpret, label=label,
     )

@@ -22,6 +22,11 @@ batch of one. Two skipping mechanisms, both bit-identical to a full scan
     bails back to the scan above ``SKIP_BLOCK_FRACTION``) and **traced**
     (frontier is a jit tracer — full-capacity list, inactive steps do
     nothing; 'auto' picks at runtime via ``lax.cond``).
+
+Every entry takes a static ``label`` (the executor's hop) that reaches the
+kernel's ``kernel_metadata`` and so the device trace; a fused region's label
+names both member hops. :func:`fuses` and :func:`streamed_blocks` state the
+dispatch decisions the executor's hop work counters need.
 """
 from __future__ import annotations
 
@@ -68,18 +73,27 @@ def _check_skip_mode(block_skipping: str) -> None:
         )
 
 
+def _may_skip(E: int, blocks, block_skipping: str) -> bool:
+    """Whether a block list can apply to the hop at all."""
+    _check_skip_mode(block_skipping)
+    if block_skipping == "off" or blocks is None or E == 0:
+        return False
+    # nothing to skip on a 1-block index; 'on' still engages the active
+    # kernel so small shapes exercise the real code path
+    return _active.n_edge_blocks(E) > 1 or block_skipping == "on"
+
+
+def _skip_threshold(E: int) -> int:
+    return max(1, int(_active.SKIP_BLOCK_FRACTION * _active.n_edge_blocks(E)))
+
+
 def _plan_skip(w, op: str, E: int, blocks, block_skipping: str):
     """Decide scan vs skip for one hop. ``None`` → full scan; otherwise
     ``(block_idx, n_active, mode)`` with mode 'static' (commit to the active
     kernel now) or 'cond' (traced 'auto': pick at runtime via lax.cond)."""
-    _check_skip_mode(block_skipping)
-    if block_skipping == "off" or blocks is None or E == 0:
+    if not _may_skip(E, blocks, block_skipping):
         return None
     nb = _active.n_edge_blocks(E)
-    if nb <= 1 and block_skipping != "on":
-        # nothing to skip on a 1-block index; 'on' still engages the active
-        # kernel so small shapes exercise the real code path
-        return None
     src_min, src_max = blocks
     zero = _IDENTITY[op]
     if isinstance(w, jax.core.Tracer):
@@ -112,10 +126,24 @@ def _skip_or_cond(plan, E: int, skip_fn, scan_fn):
     bi, na, mode = plan
     if mode == "static":
         return skip_fn(bi, na)
-    thresh = max(1, int(_active.SKIP_BLOCK_FRACTION * _active.n_edge_blocks(E)))
     return jax.lax.cond(
-        na[0] <= thresh, lambda: skip_fn(bi, na), scan_fn
+        na[0] <= _skip_threshold(E), lambda: skip_fn(bi, na), scan_fn
     )
+
+
+def streamed_blocks(w, op: str, E: int, blocks, block_skipping: str):
+    """The edge blocks :func:`hop` computes over for a traced frontier ``w``
+    (int32 scalar): every block, or the surviving ones where the traced
+    tier's block list applies ('on', or 'auto' under its threshold)."""
+    nb = _active.n_edge_blocks(E)
+    if not _may_skip(E, blocks, block_skipping):
+        return jnp.int32(nb)
+    _, na = _active.active_block_list(
+        w, _IDENTITY[op], jnp.asarray(blocks[0]), jnp.asarray(blocks[1])
+    )
+    if block_skipping == "on":
+        return na[0]
+    return jnp.where(na[0] <= _skip_threshold(E), na[0], jnp.int32(nb))
 
 
 def bitunpack(packed, width: int, count: int, use_pallas: bool = True):
@@ -138,7 +166,7 @@ def _measure_operands(measure, mdict, m_mode: str):
 def hop(weights, src, dst, measure=None, mdict=None, *, n_dst: int,
         src_width: int = 0, dst_width: int = 0, m_mode: str = "none",
         m_width: int = 0, op: str = "sum", blocks=None,
-        block_skipping: str = "off"):
+        block_skipping: str = "off", label: str = ""):
     """One hop through the Pallas kernel over a ``[B, n_src]`` frontier.
     ``src``/``dst`` are dense int32 ids or BCA words (``*_width`` > 0), in
     any edge order; ``blocks`` is the (src_min, src_max) skip metadata of
@@ -150,7 +178,7 @@ def hop(weights, src, dst, measure=None, mdict=None, *, n_dst: int,
     kw = dict(
         n_dst=n_dst, src_width=src_width, dst_width=dst_width, m_mode=m_mode,
         m_width=m_width, op=op, skip_chunks=block_skipping != "off",
-        interpret=_interpret(),
+        interpret=_interpret(), label=label,
     )
     scan = lambda: _fragment_hop(w, s, d, m, md, None, None, **kw)
     E = n_edges(s, src_width, d, dst_width)
@@ -164,7 +192,7 @@ def hop(weights, src, dst, measure=None, mdict=None, *, n_dst: int,
 
 def fragment_spmv(weights, src_ids, dst_ids, measures, n_dst: int,
                   op: str = "sum", use_pallas: bool = True,
-                  blocks=None, block_skipping: str = "off"):
+                  blocks=None, block_skipping: str = "off", label: str = ""):
     """Single-query hop ``y[dst] ⊕= w[src] ⊗ m``: the batched hop with a
     batch of one."""
     w = jnp.asarray(weights, jnp.float32)
@@ -175,12 +203,12 @@ def fragment_spmv(weights, src_ids, dst_ids, measures, n_dst: int,
         return ref.fragment_spmv_ref(w, s, d, m, n_dst, op=op)
     _faults.fire("ops.fragment_spmv", op=op, n_dst=n_dst)
     return hop(w[None], s, d, m, n_dst=n_dst, m_mode="dense", op=op,
-               blocks=blocks, block_skipping=block_skipping)[0]
+               blocks=blocks, block_skipping=block_skipping, label=label)[0]
 
 
 def fragment_spmm(weights, src_ids, dst_ids, measures, n_dst: int,
                   op: str = "sum", use_pallas: bool = True,
-                  blocks=None, block_skipping: str = "off"):
+                  blocks=None, block_skipping: str = "off", label: str = ""):
     """Batched multi-query hop: ``Y[b, dst] ⊕= W[b, src] ⊗ m`` with one edge
     stream serving all B frontier rows. ``measures`` may be [E] (shared) or
     [B, E] (per-row, e.g. a seed-scalar-dependent measure expression):
@@ -194,7 +222,7 @@ def fragment_spmm(weights, src_ids, dst_ids, measures, n_dst: int,
         return ref.fragment_spmm_ref(w, s, d, m, n_dst, op=op)
     _faults.fire("ops.fragment_spmm", op=op, n_dst=n_dst)
     return hop(w, s, d, m, n_dst=n_dst, m_mode="dense", op=op,
-               blocks=blocks, block_skipping=block_skipping)
+               blocks=blocks, block_skipping=block_skipping, label=label)
 
 
 def fragment_spmm_packed(weights, src_ids, dst, measure=None, mdict=None, *,
@@ -202,7 +230,7 @@ def fragment_spmm_packed(weights, src_ids, dst, measure=None, mdict=None, *,
                          m_width: int = 0, op: str = "sum",
                          use_pallas: bool = True,
                          blocks=None, block_skipping: str = "off",
-                         src_width: int = 0):
+                         src_width: int = 0, label: str = ""):
     """Decode-fused batched hop: packed src/dst/measure word streams decode
     once per 4096-edge block in VMEM and serve all B frontier rows."""
     if not use_pallas:
@@ -221,7 +249,7 @@ def fragment_spmm_packed(weights, src_ids, dst, measure=None, mdict=None, *,
     return hop(weights, src_ids, dst, measure, mdict, n_dst=n_dst,
                src_width=src_width, dst_width=dst_width, m_mode=m_mode,
                m_width=m_width, op=op, blocks=blocks,
-               block_skipping=block_skipping)
+               block_skipping=block_skipping, label=label)
 
 
 def fragment_spmv_packed(weights, src_ids, dst, measure=None, mdict=None, *,
@@ -229,7 +257,7 @@ def fragment_spmv_packed(weights, src_ids, dst, measure=None, mdict=None, *,
                          m_width: int = 0, op: str = "sum",
                          use_pallas: bool = True,
                          blocks=None, block_skipping: str = "off",
-                         src_width: int = 0):
+                         src_width: int = 0, label: str = ""):
     """Decode-fused single-query hop (the batched one with a batch of one)."""
     w = jnp.asarray(weights, jnp.float32)
     if not use_pallas:
@@ -242,7 +270,7 @@ def fragment_spmv_packed(weights, src_ids, dst, measure=None, mdict=None, *,
     return hop(w[None], src_ids, dst, measure, mdict, n_dst=n_dst,
                src_width=src_width, dst_width=dst_width, m_mode=m_mode,
                m_width=m_width, op=op, blocks=blocks,
-               block_skipping=block_skipping)[0]
+               block_skipping=block_skipping, label=label)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -293,9 +321,21 @@ def _fusion_unfusable(fusion: str, n_mid: int) -> bool:
     return 4 * GROUP_ROWS * padded(n_mid) > FUSED_VMEM_BUDGET_BYTES
 
 
+def fuses(hop1: FusedHopOperands, hop2: FusedHopOperands | None,
+          fusion: str, use_pallas: bool) -> bool:
+    """Whether a region runs the single-pass fused kernel; otherwise its
+    member hops run through the unfused kernels (or XLA without Pallas)."""
+    return (
+        use_pallas
+        and not _fusion_unfusable(fusion, hop1.n_dst)
+        and hop1.n_edges() > 0
+        and (hop2 is None or hop2.n_edges() > 0)
+    )
+
+
 def _compose_unfused(packed_fn, weights, hop1, hop2, mid_mask,
                      mid_binarize: bool, op: str, use_pallas: bool,
-                     block_skipping: str):
+                     block_skipping: str, labels: tuple):
     """The member hops through the unfused kernels (fusion off / VMEM budget
     exceeded / empty relation) — the reference semantics the fused kernel must
     match bit-for-bit."""
@@ -304,6 +344,7 @@ def _compose_unfused(packed_fn, weights, hop1, hop2, mid_mask,
         n_dst=hop1.n_dst, dst_width=hop1.dst_width, m_mode=hop1.m_mode,
         m_width=hop1.m_width, op=op, use_pallas=use_pallas,
         block_skipping=block_skipping, src_width=hop1.src_width,
+        label=labels[0],
     )
     if mid_mask is not None:
         keep = mid_mask[None, :] if u.ndim == 2 else mid_mask
@@ -317,28 +358,23 @@ def _compose_unfused(packed_fn, weights, hop1, hop2, mid_mask,
         n_dst=hop2.n_dst, dst_width=hop2.dst_width, m_mode=hop2.m_mode,
         m_width=hop2.m_width, op=op, use_pallas=use_pallas,
         block_skipping=block_skipping, src_width=hop2.src_width,
+        label=labels[1],
     )
 
 
 def _fused_dispatch(batched: bool, weights, hop1, hop2, mid_mask, *, op,
-                    mid_binarize, use_pallas, fusion, block_skipping):
+                    mid_binarize, use_pallas, fusion, block_skipping, labels):
     _check_skip_mode(block_skipping)
     w = jnp.asarray(weights, jnp.float32)
     mm = jnp.asarray(mid_mask, jnp.float32) if mid_mask is not None else None
-    E1 = hop1.n_edges()
-    E2 = hop2.n_edges() if hop2 is not None else 0
     n_mid = hop1.n_dst
     n_dst = hop2.n_dst if hop2 is not None else hop1.n_dst
     packed_fn = fragment_spmm_packed if batched else fragment_spmv_packed
-    if (
-        not use_pallas
-        or _fusion_unfusable(fusion, n_mid)
-        or E1 == 0
-        or (hop2 is not None and E2 == 0)
-    ):
+    labels = tuple(labels) + ("",) * (2 - len(labels))
+    if not fuses(hop1, hop2, fusion, use_pallas):
         return _compose_unfused(
             packed_fn, w, hop1, hop2, mm, mid_binarize, op,
-            use_pallas, block_skipping,
+            use_pallas, block_skipping, labels,
         )
     site = "ops.fragment_spmm_fused" if batched else "ops.fragment_spmv_fused"
     _faults.fire(site, op=op, n_dst=n_dst)
@@ -350,6 +386,7 @@ def _fused_dispatch(batched: bool, weights, hop1, hop2, mid_mask, *, op,
         cfg2_modes=hop2.modes() if hop2 is not None else None,
         op=op, mid_binarize=mid_binarize and hop2 is not None,
         skip_chunks=block_skipping != "off", interpret=_interpret(),
+        label="+".join(x for x in labels if x),
     )
     return out if batched else out[0]
 
@@ -358,7 +395,7 @@ def fragment_spmv_fused(weights, hop1: FusedHopOperands,
                         hop2: FusedHopOperands | None = None, mid_mask=None,
                         *, op: str = "sum", mid_binarize: bool = False,
                         use_pallas: bool = True, fusion: str = "auto",
-                        block_skipping: str = "off"):
+                        block_skipping: str = "off", labels: tuple = ()):
     """Pipelined fused region: hop1 → in-register mask/binarize → hop2 in one
     kernel pass, the intermediate frontier resident in VMEM scratch
     (``hop2=None`` ⇒ degenerate 1-hop+filter region). Bit-identical to the
@@ -366,7 +403,7 @@ def fragment_spmv_fused(weights, hop1: FusedHopOperands,
     return _fused_dispatch(
         False, weights, hop1, hop2, mid_mask, op=op,
         mid_binarize=mid_binarize, use_pallas=use_pallas, fusion=fusion,
-        block_skipping=block_skipping,
+        block_skipping=block_skipping, labels=labels,
     )
 
 
@@ -374,13 +411,13 @@ def fragment_spmm_fused(weights, hop1: FusedHopOperands,
                         hop2: FusedHopOperands | None = None, mid_mask=None,
                         *, op: str = "sum", mid_binarize: bool = False,
                         use_pallas: bool = True, fusion: str = "auto",
-                        block_skipping: str = "off"):
+                        block_skipping: str = "off", labels: tuple = ()):
     """Batched pipelined region: B queries share the single fused pass, the
     ``[B, n_mid]`` intermediate resident in VMEM scratch."""
     return _fused_dispatch(
         True, weights, hop1, hop2, mid_mask, op=op,
         mid_binarize=mid_binarize, use_pallas=use_pallas, fusion=fusion,
-        block_skipping=block_skipping,
+        block_skipping=block_skipping, labels=labels,
     )
 
 

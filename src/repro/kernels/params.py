@@ -11,6 +11,7 @@ exactly ``128·width`` words — a static BlockSpec, no halo.
 from __future__ import annotations
 
 EDGE_BLOCK = 4096  # edges per grid step; 32 rows of 128 lanes
+LANES = 128  # edges per row; frontier entries per chunk
 
 #: Upper bound on the scoped-VMEM request of one hop kernel. The kernels keep
 #: ``(8, n)`` frontier and accumulator groups resident; the compiler's default

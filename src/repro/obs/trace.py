@@ -7,11 +7,26 @@ spans, and the per-IR-op spans the profiling walk emits from
 ContextVar, so recording composes with nested calls and never leaks across
 threads/async contexts.
 
+Profiler clock: while a JAX profiler session is collecting
+(``jax.profiler.start_trace``), every span also opens a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>`` whose stats are the
+span's metadata, so the program's host path lands in the profiler trace on
+the device trace's clock. Spans opened under a profiler session with no
+recording tracer installed record into :data:`PROFILED`, a bounded tracer
+that in-process readers (the benchmark's per-layer metrics) read after the
+session (one tree for the process: spans that threads open concurrently
+interleave in it). A profiler session never installs a tracer: code that
+branches on :func:`current` (the executor's eager per-op walk) keeps its
+compiled path.
+
 Zero-overhead contract: tracing is **off by default** and the disabled fast
-path allocates nothing — :func:`span` returns the module-level
-:data:`NULL_SPAN` singleton (a no-op context manager with ``__slots__ = ()``),
-and :func:`annotate` is one ContextVar read plus a ``None`` check. Nothing in
-this module imports jax at module load; :meth:`Span.fence` imports it lazily.
+path allocates nothing — with no tracer and no profiler session,
+:func:`span` returns the module-level :data:`NULL_SPAN` singleton (a no-op
+context manager with ``__slots__ = ()``) after one ContextVar read and one
+``TraceMe.is_enabled()`` check, and :func:`annotate` is one ContextVar read
+plus a ``None`` check. Nothing in this module imports jax at module load
+(only jaxlib's profiler binding); :meth:`Span.fence` and the profiler
+annotation import it lazily.
 
 jit safety: spans record *around* traced calls, never inside them — the
 instrumented walker guards on ``jax.core.trace_ctx.is_top_level()`` and degrades to
@@ -25,7 +40,18 @@ import json
 import time
 from contextvars import ContextVar
 
+from jaxlib._profiler import TraceMe as _TraceMe
+
 _TRACER: ContextVar["Tracer | None"] = ContextVar("repro_obs_tracer", default=None)
+
+#: Prefix of the profiler-trace events spans open (``repro.<span name>``).
+PREFIX = "repro."
+
+profiling = _TraceMe.is_enabled  # () -> bool: a profiler session is collecting
+
+
+def _stat(v):
+    return v if isinstance(v, (int, float, str, bool)) else str(v)
 
 
 class Span:
@@ -33,7 +59,8 @@ class Span:
     optional device-sync'd kernel time recorded by :meth:`fence` — the
     ``block_until_ready``-fenced duration from span entry to device-done."""
 
-    __slots__ = ("name", "meta", "children", "status", "t0", "wall_ms", "kernel_ms")
+    __slots__ = ("name", "meta", "children", "status", "t0", "wall_ms", "kernel_ms",
+                 "_tracer", "_event")
 
     def __init__(self, name: str, **meta):
         self.name = name
@@ -43,9 +70,13 @@ class Span:
         self.t0 = 0.0
         self.wall_ms: float | None = None
         self.kernel_ms: float | None = None
+        self._tracer: Tracer | None = None
+        self._event = None  # the open profiler annotation, if any
 
     def annotate(self, **kv) -> None:
         self.meta.update(kv)
+        if self._event is not None:
+            self._event.set_metadata(**{k: _stat(v) for k, v in kv.items()})
 
     def fence(self, value):
         """Block until the device work backing ``value`` completes and record
@@ -60,8 +91,18 @@ class Span:
 
     def __enter__(self) -> "Span":
         tr = _TRACER.get()
+        if profiling():
+            from jax.profiler import TraceAnnotation
+
+            if tr is None:
+                tr = PROFILED
+            self._event = TraceAnnotation(
+                PREFIX + self.name, **{k: _stat(v) for k, v in self.meta.items()}
+            )
+            self._event.__enter__()
         if tr is not None:
             tr._attach(self)
+            self._tracer = tr
         self.t0 = time.perf_counter()
         return self
 
@@ -69,9 +110,11 @@ class Span:
         self.wall_ms = (time.perf_counter() - self.t0) * 1e3
         if exc_type is not None:
             self.status = f"error:{exc_type.__name__}"
-        tr = _TRACER.get()
-        if tr is not None:
-            tr._detach(self)
+        if self._event is not None:
+            self._event.__exit__(exc_type, exc, tb)
+            self._event = None
+        if self._tracer is not None:
+            self._tracer._detach(self)
         return False  # never swallow the exception
 
     def self_wall_ms(self) -> float | None:
@@ -123,13 +166,16 @@ class Tracer:
     ``Span.__exit__`` pops everything above (and including) itself, so a span
     abandoned by an exception mid-subtree cannot corrupt later nesting."""
 
-    def __init__(self):
+    def __init__(self, max_roots: int | None = None):
         self.roots: list[Span] = []
         self._stack: list[Span] = []
+        self.max_roots = max_roots  # None: unbounded; else the oldest half goes
 
     def _attach(self, sp: Span) -> None:
         (self._stack[-1].children if self._stack else self.roots).append(sp)
         self._stack.append(sp)
+        if self.max_roots is not None and len(self.roots) > self.max_roots:
+            del self.roots[: len(self.roots) // 2]
 
     def _detach(self, sp: Span) -> None:
         while self._stack:
@@ -155,6 +201,10 @@ class Tracer:
         return json.dumps(self.to_dict(), **kw)
 
 
+#: Where spans opened under a profiler session with no recording tracer go.
+PROFILED = Tracer(max_roots=1 << 16)
+
+
 def current() -> Tracer | None:
     """The active tracer, or None when tracing is disabled (the default)."""
     return _TRACER.get()
@@ -164,10 +214,18 @@ def enabled() -> bool:
     return _TRACER.get() is not None
 
 
+def tracing() -> bool:
+    """Whether :func:`span` records: a tracer is installed or a profiler
+    session is collecting."""
+    return _TRACER.get() is not None or profiling()
+
+
 def span(name: str, **meta):
-    """Open a span under the active tracer; the :data:`NULL_SPAN` no-op when
-    tracing is disabled. Use as ``with span("lower") as sp: ...``."""
-    if _TRACER.get() is None:
+    """Open a span under the active tracer (or, under a profiler session,
+    :data:`PROFILED`), also as a ``repro.<name>`` profiler event; the
+    :data:`NULL_SPAN` no-op when neither is on. Use as
+    ``with span("lower") as sp: ...``."""
+    if _TRACER.get() is None and not profiling():
         return NULL_SPAN
     return Span(name, **meta)
 

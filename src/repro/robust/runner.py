@@ -37,6 +37,18 @@ under a fault-tolerance policy instead of letting exceptions escape:
 
 Every execution attempt passes the ``runner.execute`` fault-injection site,
 so chaos tests can fail/delay attempts without touching kernel internals.
+
+Host-path spans (``obs.trace``; ``repro.<name>`` events in a profiler
+trace, nothing at all when tracing is off): ``batch`` (stats ``query``,
+``rows``, ``bucket``) around :func:`run_batch_with_policy`, holding
+``args`` (validation and padding), ``admit`` (only with admission
+control), and per attempt ``attempt`` (``rung``, ``attempt``) holding
+``dispatch`` (the executable's call, until it returns), ``device_wait``
+(``block_until_ready``) and ``fetch`` (the result to host memory), then
+``hops`` — the batched executable's hop work counters, fetched only while
+tracing — and ``backoff`` (a retry's sleep). Building a rung's executable
+runs in a ``compile`` span.
+
 Outcomes are returned, never raised: :class:`QueryOutcome` carries the value,
 the rung that produced it, degradation status, and the terminal
 :class:`QueryError` when all rungs failed.
@@ -51,6 +63,7 @@ from typing import Any
 
 import numpy as np
 
+from ..obs import trace as T
 from ..obs.metrics import REGISTRY, MetricsRegistry
 from . import faults
 from .admission import AdmissionController
@@ -224,31 +237,34 @@ def rung_fn(prepared, rung: str, batched: bool = False):
 
     from ..core.fuse import unfuse_plan
 
-    db, phys = prepared.device_db, prepared.phys
-    # every rung below "active" runs the unfused twin of the plan: a fault in
-    # the fused kernel dispatch must not follow the query down the ladder
-    # (the frontier interps replay fused regions per-op only when told to)
-    uphys = unfuse_plan(phys) if phys is not None else phys
     if rung == "active":
         fn = prepared.batched_fn if batched else prepared.fn
         if batched and fn is None:  # strategies without a batched entry
             fn = jax.vmap(prepared.fn)
-    elif rung == "unfused":
-        mk = X.compile_frontier_batched if batched else X.compile_frontier
-        fn = mk(db, uphys, block_skipping=prepared.block_skipping,
-                fusion="off")
-    elif rung == "scan":
-        mk = X.compile_frontier_batched if batched else X.compile_frontier
-        fn = mk(db, uphys, block_skipping="off", fusion="off")
-    elif rung == "xla":
-        mk = X.compile_frontier_batched if batched else X.compile_frontier
-        fn = mk(db, uphys, block_skipping="off", use_pallas=False,
-                fusion="off")
-    elif rung == "fragment_loop":
-        single = X.compile_fragment_loop(db, uphys, use_pallas=False)
-        fn = jax.vmap(single) if batched else single
-    else:
+        cache[key] = fn
+        return fn
+    if rung not in LADDER:
         raise ValueError(f"unknown ladder rung {rung!r}; valid: {LADDER}")
+    db, phys = prepared.device_db, prepared.phys
+    mk = X.compile_frontier_batched if batched else X.compile_frontier
+    name = f"gqfast_frontier{'_batched' if batched else ''}_{rung}"
+    with T.span("compile", rung=rung, batched=batched):
+        # every rung below "active" runs the unfused twin of the plan: a fault
+        # in the fused kernel dispatch must not follow the query down the
+        # ladder (the frontier interps replay fused regions per-op only when
+        # told to)
+        uphys = unfuse_plan(phys) if phys is not None else phys
+        if rung == "unfused":
+            fn = mk(db, uphys, block_skipping=prepared.block_skipping,
+                    fusion="off", name=name)
+        elif rung == "scan":
+            fn = mk(db, uphys, block_skipping="off", fusion="off", name=name)
+        elif rung == "xla":
+            fn = mk(db, uphys, block_skipping="off", use_pallas=False,
+                    fusion="off", name=name)
+        else:  # fragment_loop
+            single = X.compile_fragment_loop(db, uphys, use_pallas=False)
+            fn = jax.vmap(single) if batched else single
     cache[key] = fn
     return fn
 
@@ -258,25 +274,58 @@ def rung_fn(prepared, rung: str, batched: bool = False):
 # ---------------------------------------------------------------------------
 
 
+def hop_stats(hops: list[dict]) -> dict:
+    """The ``hops`` span's stats from ``HopCounts.decode()``: totals
+    ``rows``, ``gathers`` and ``gather_rows`` (the rows of the hops whose
+    gathers are counted), and per hop ``h<k>`` = ``"<label> rows=<n>
+    gathers=<n|-> active=<n|-> chunks=<n>"``."""
+    st = {"rows": 0, "gathers": 0, "gather_rows": 0}
+    for h in hops:
+        st["rows"] += h["rows"]
+        if h["gathers"] is not None:
+            st["gathers"] += h["gathers"]
+            st["gather_rows"] += h["rows"]
+        key, _, label = h["label"].partition(":")
+        gathers, active = (("-" if v is None else v)
+                           for v in (h["gathers"], h["active_chunks"]))
+        st[key] = (f"{label} rows={h['rows']} gathers={gathers} "
+                   f"active={active} chunks={h['chunks']}")
+    return st
+
+
 def _attempt(prepared, rung: str, args, deadline: Deadline | None,
              batched: bool):
     """One execution attempt on one rung: fault site → compile/lookup →
-    call → device fence → deadline check. Raises QueryError on any failure."""
+    call → device fence → deadline check → fetch. Raises QueryError on any
+    failure. While tracing, a counting executable's hop counters are
+    fetched too and emitted as the ``hops`` span's stats."""
     import jax
 
     faults.fire("runner.execute", rung=rung, query=prepared.sql.strip()[:80])
+    counts = None
     try:
         with deadline_scope(deadline):
             fn = rung_fn(prepared, rung, batched=batched)
-            out = fn(*args)
-            jax.block_until_ready(out)
+            counted = getattr(fn, "counted", None) if T.tracing() else None
+            with T.span("dispatch"):
+                if counted is not None:
+                    out, counts = counted(*args)
+                else:
+                    out = fn(*args)
+            with T.span("device_wait"):
+                jax.block_until_ready(out)
     except QueryError:
         raise
     except Exception as e:  # noqa: BLE001 — normalize foreign exceptions
         raise wrap_execution_error(e, rung=rung, strategy=prepared.strategy)
     if deadline is not None:
         deadline.check("block_until_ready")
-    return np.asarray(out)
+    with T.span("fetch"):
+        value = np.asarray(out)
+    if counts is not None:
+        with T.span("hops") as sp:
+            sp.annotate(**hop_stats(counts.decode()))
+    return value
 
 
 def _run_ladder(prepared, args, policy: RobustPolicy,
@@ -290,7 +339,8 @@ def _run_ladder(prepared, args, policy: RobustPolicy,
         while True:
             attempts += 1
             try:
-                value = _attempt(prepared, rung, args, deadline, batched)
+                with T.span("attempt", rung=rung, attempt=attempts):
+                    value = _attempt(prepared, rung, args, deadline, batched)
                 status = (
                     "ok" if attempts == 1 and not demotions else "degraded"
                 )
@@ -317,7 +367,8 @@ def _run_ladder(prepared, args, policy: RobustPolicy,
                     reg.counter("robust.retries").inc()
                     wait = policy.retry.backoff_ms(retries, policy._rng)
                     if deadline is None or deadline.remaining_ms() > wait:
-                        time.sleep(wait / 1e3)
+                        with T.span("backoff", ms=round(wait, 3)):
+                            time.sleep(wait / 1e3)
                         continue
                 break  # exhausted retries (or no time to back off): demote
         demotions.append(rung)
@@ -362,6 +413,12 @@ def run_batch_with_policy(
     a surviving batch share status/rung; a rejected/failed batch yields per-
     row error outcomes). Admission may *demote* an over-budget batch to
     serial single-query execution — degraded, but within budget."""
+    with T.span("batch", query=getattr(prepared, "query_id", "")) as sp:
+        return _run_batch(prepared, param_arrays, deadline_ms, policy, sp)
+
+
+def _run_batch(prepared, param_arrays: dict, deadline_ms: float | None,
+               policy: RobustPolicy | None, sp) -> list[QueryOutcome]:
     from ..core.engine import batch_bucket
 
     policy = policy if policy is not None else RobustPolicy()
@@ -369,17 +426,29 @@ def run_batch_with_policy(
     dms = deadline_ms if deadline_ms is not None else policy.deadline_ms
     deadline = Deadline(dms) if dms is not None else None
     try:
-        args, B = prepared._batch_args(param_arrays)
+        with T.span("args"):
+            args, B = prepared._batch_args(param_arrays)
+            # pad to the bucket (repeating the last row) so recompiles stay
+            # bounded; serial demotion reads only the first B rows
+            bucket = batch_bucket(B)
+            padded = args
+            if bucket != B:
+                padded = [
+                    np.concatenate([a, np.repeat(a[-1:], bucket - B, axis=0)])
+                    for a in args
+                ]
     except QueryError as e:
         policy.registry.counter(f"robust.errors.{e.code}").inc()
         n = _best_effort_batch_len(param_arrays)
         out = QueryOutcome("error", None, policy.ladder[0], 0, error=e)
         return [out] * max(n, 1)
+    sp.annotate(rows=B, bucket=bucket)
     serial = False
     if policy.admission is not None:
         try:
-            decision = policy.admission.admit(prepared, batch=B,
-                                              allow_demote=True)
+            with T.span("admit"):
+                decision = policy.admission.admit(prepared, batch=B,
+                                                  allow_demote=True)
             serial = decision.action == "demote"
         except QueryError as e:
             policy.registry.counter(f"robust.errors.{e.code}").inc()
@@ -399,13 +468,7 @@ def run_batch_with_policy(
                 oc.status = "degraded"
             outs.append(oc)
         return outs
-    bucket = batch_bucket(B)
-    if bucket != B:
-        args = [
-            np.concatenate([a, np.repeat(a[-1:], bucket - B, axis=0)])
-            for a in args
-        ]
-    oc = _run_ladder(prepared, args, policy, deadline, True, t0)
+    oc = _run_ladder(prepared, padded, policy, deadline, True, t0)
     if oc.value is not None:
         rows = oc.value[:B]
         return [

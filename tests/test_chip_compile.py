@@ -5,7 +5,9 @@ never asks the TPU compiler (Mosaic) anything. These tests compile the
 kernels the served path reaches, at the widths of the bring-up deployment
 (1M documents, 27,883 terms, ~270k authors), for a ``v5e:2x2`` topology
 that is described, not attached: dense and packed streams, full scans and
-block lists, one query and a batch of eight, and one fused two-hop region.
+block lists, one query and a batch of eight, and one fused two-hop region;
+and that a hop's label reaches the compiled kernel's ``kernel_metadata``
+under the unchanged instruction names ``gqfast_hop`` / ``gqfast_fused_hops``.
 A compile takes a second or two; it proves the kernel lowers and fits VMEM,
 not that it is fast or right (the interpret-mode tests cover results).
 
@@ -14,6 +16,7 @@ only one process at a time may load the TPU library, and every test worker
 imports every test module.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -115,3 +118,43 @@ def test_fused_author_doc_term(sds, B):
         n_mid=N_DOCS, n_dst=N_TERMS, cfg1_modes=(0, 0, "none", 0),
         cfg2_modes=(20, 0, "dense", 0), op="sum", mid_binarize=True,
     )
+
+
+def _kernel_lines(compiled, name: str) -> list[str]:
+    """The compiled module's instructions named ``name`` (``%name`` or
+    ``%name.N``), each with the text up to the next instruction (the
+    kernel's metadata JSON spans lines)."""
+    text = compiled.as_text()
+    return [chunk for chunk in re.split(r"\n(?=\s*(?:ROOT\s+)?%)", text)
+            if re.match(rf"\s*(?:ROOT\s+)?%{name}(\.\d+)? = ", chunk)]
+
+
+def test_hop_label_reaches_kernel_metadata(sds):
+    """The executor's hop label goes into the kernel's ``kernel_metadata``
+    (what a device trace's event shows); the instruction keeps its name."""
+    label = "h2:DT.Term->Document:pull"
+    compiled = _compile(
+        fragment_hop,
+        sds((8, N_TERMS), jnp.float32), sds((E,), jnp.int32),
+        sds((E,), jnp.int32), sds((E,), jnp.float32), None, None, None,
+        n_dst=N_DOCS, m_mode="dense", op="sum", label=label,
+    )
+    lines = _kernel_lines(compiled, "gqfast_hop")
+    assert len(lines) == 1
+    assert "kernel_metadata" in lines[0] and f'"hop":"{label}"' in lines[0]
+
+
+def test_fused_label_names_both_hops(sds):
+    label = "h0:DA.Author->Document:pull+h1:DT.Doc->Term:fused"
+    hop1 = (sds((E,), jnp.int32), sds((E,), jnp.int32), None, None)
+    hop2 = (sds((E,), jnp.int32), sds((E,), jnp.int32), None, None)
+    compiled = _compile(
+        fragment_hops_fused,
+        sds((8, N_AUTHORS), jnp.float32), hop1, hop2, None,
+        n_mid=N_DOCS, n_dst=N_TERMS, cfg1_modes=(0, 0, "none", 0),
+        cfg2_modes=(0, 0, "none", 0), op="sum", label=label,
+    )
+    lines = _kernel_lines(compiled, "gqfast_fused_hops")
+    assert len(lines) == 1
+    assert f'"hop":"{label}"' in lines[0]
+    assert not _kernel_lines(compiled, "gqfast_hop")

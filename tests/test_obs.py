@@ -296,3 +296,132 @@ def test_prepare_emits_lifecycle_spans(small_db):
     prep = tr.roots[0]
     assert prep.name == "prepare"
     assert [c.name for c in prep.children] == ["parse", "plan", "lower", "compile"]
+
+
+# ------------------------------------------------- the profiler's clock
+
+
+def _repro_events(trace_dir):
+    """Every ``repro.*`` host event of the newest profile under
+    ``trace_dir``: (name, start ns, end ns, stats)."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path = max(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(T.PREFIX):
+                    s = float(ev.start_ns)
+                    out.append((ev.name, s, s + float(ev.duration_ns),
+                                dict(ev.stats)))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def _inside(inner, outer) -> bool:
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def test_runner_spans_reach_a_profiler_trace(small_db, tmp_path):
+    """Under a profiler session (no recorder installed) the runner's spans
+    are ``repro.*`` events: batch ⊃ attempt ⊃ dispatch, device_wait, fetch,
+    then one ``repro.hops`` per batch carrying the hop counters; they also
+    land in ``PROFILED``. Without a session nothing is recorded."""
+    import jax
+
+    from repro.robust import RobustPolicy, run_batch_with_policy
+
+    eng = GQFastEngine(small_db, strategy="frontier")
+    pq = eng.prepare(QUERY_AS)
+    policy = RobustPolicy(registry=M.MetricsRegistry())
+    batch = {"a0": np.array([3, 5, 5])}
+    run_batch_with_policy(pq, batch, policy=policy)  # compile outside
+    T.PROFILED.roots.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(2):
+            outs = run_batch_with_policy(pq, batch, policy=policy)
+    finally:
+        jax.profiler.stop_trace()
+    assert all(o.status == "ok" for o in outs)
+    assert not T.profiling() and T.current() is None
+
+    evs = _repro_events(str(tmp_path))
+    by = lambda n: [e for e in evs if e[0] == T.PREFIX + n]  # noqa: E731
+    batches = by("batch")
+    assert len(batches) == 2
+    assert not by("compile")
+    for b in batches:
+        assert b[3]["rows"] == 3 and b[3]["bucket"] == 4
+        assert b[3]["query"] == pq.query_id
+        (att,) = [e for e in by("attempt") if _inside(e, b)]
+        assert att[3]["rung"] == "active" and att[3]["attempt"] == 1
+        (args,) = [e for e in by("args") if _inside(e, b)]
+        assert args[2] <= att[1]
+        steps = [e for e in evs if _inside(e, att) and e[0] in
+                 (T.PREFIX + "dispatch", T.PREFIX + "device_wait",
+                  T.PREFIX + "fetch", T.PREFIX + "hops")]
+        assert [e[0][len(T.PREFIX):] for e in steps] == [
+            "dispatch", "device_wait", "fetch", "hops"]
+        hops = steps[-1][3]
+        assert hops["rows"] > 0 and hops["gather_rows"] > 0
+        assert hops["h0"].startswith("DA.Author->Document:pull rows=")
+    # the same tree, recorded in-process on perf_counter
+    roots = [sp for sp in T.PROFILED.roots if sp.name == "batch"]
+    assert len(roots) == 2
+    att = roots[0].children[-1]
+    assert [c.name for c in att.children] == [
+        "dispatch", "device_wait", "fetch", "hops"]
+    assert att.children[-1].meta["rows"] == hops["rows"]
+
+    T.PROFILED.roots.clear()
+    run_batch_with_policy(pq, batch, policy=policy)
+    assert T.PROFILED.roots == []
+    assert T.span("batch") is T.NULL_SPAN
+
+
+def test_disabled_span_path_allocates_nothing():
+    """No tracer, no profiler session: a span costs no memory at all."""
+    import tracemalloc
+
+    assert not T.tracing()
+    for _ in range(10):  # warm any lazily created state
+        with T.span("dispatch"):
+            pass
+    tracemalloc.start()
+    base = tracemalloc.get_traced_memory()[0]
+    for _ in range(10_000):
+        with T.span("dispatch"):
+            pass
+    grown, peak = (m - base for m in tracemalloc.get_traced_memory())
+    tracemalloc.stop()
+    # 10,000 Spans would take megabytes, one live Span hundreds of bytes
+    assert grown < 1024 and peak < 1024, (grown, peak)
+
+
+def test_profiler_alone_keeps_the_compiled_walk(small_db, tmp_path):
+    """A profiler session installs no recorder: the executor's eager per-op
+    walk (``_walk_ir_recorded``) stays off and results are unchanged."""
+    import jax
+
+    from repro.core import executor as X
+
+    eng = GQFastEngine(small_db, strategy="frontier")
+    pq = eng.prepare(QUERY_SD)
+    plain = np.asarray(pq(d0=9))
+    calls = []
+    orig = X._walk_ir_recorded
+    X._walk_ir_recorded = lambda *a: calls.append(a) or orig(*a)
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            traced = np.asarray(pq(d0=9))
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        X._walk_ir_recorded = orig
+    assert calls == [] and np.array_equal(plain, traced)
