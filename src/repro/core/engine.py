@@ -222,11 +222,12 @@ class PreparedQuery:
 
     def hop_routes(self, batched: bool = False) -> list[tuple[str, str]]:
         """Where each hop of the plan (mask sub-programs included) runs:
-        ``pallas`` — the hop kernel over the destination-sorted edges of the
-        reverse index — ``pallas (source-sorted)`` — the same kernel over the
-        hop's own index, where the database holds no reverse index — or
-        ``xla: <reason>`` for hops that stay on XLA by design. The ladder can
-        still demote a Pallas hop at run time; this is the plan's route."""
+        ``pallas`` — the hop kernel over the pull stream of the reverse
+        index (``executor.PullStream``) — ``pallas (source-sorted)`` — the
+        same kernel over the hop's own index, where the database holds no
+        reverse index — or ``xla: <reason>`` for hops that stay on XLA by
+        design. The ladder can still demote a Pallas hop at run time; this
+        is the plan's route."""
         from .lower import HopOp, SeedOp, batch_dependent, iter_flat_ops
 
         def walk(phys):
@@ -362,6 +363,8 @@ class GQFastEngine:
         self.calibration = CalibrationStore()
         # the mesh's edge-sharded placement, shared by every prepared query
         self._sharded_db = None
+        if mesh is None:  # the single-chip hop kernels read pull streams
+            X.attach_pull_streams(db.device)
 
     def invalidate_prepared(self) -> int:
         """Drop every cached prepared query. Required after the device arrays

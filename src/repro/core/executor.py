@@ -8,11 +8,12 @@ primitive each op maps to:
   * ``frontier`` — bottom-up fully pipelined execution, TPU-native: each HopOp
     runs the Pallas hop kernel (:mod:`repro.kernels.fragment_spmv`; compiled
     on TPU, interpret mode on CPU) over dense per-entity-domain vectors and
-    the hop's destination-sorted edges (``HopOp.pull``: the index keyed on
-    the destination). Bit-packed columns of the device column store
-    (:mod:`repro.storage`) decode block-at-a-time in VMEM inside the kernel
-    (the paper's compression-inside-the-operator design). JAX tracing fuses
-    the whole plan into one XLA executable; intermediates are vectors, never
+    the hop's pull stream (``HopOp.pull``: the edges of the index keyed on
+    the destination, each block sorted by source — ``PullStream``).
+    Bit-packed columns of the device column store (:mod:`repro.storage`)
+    decode block-at-a-time in VMEM inside the kernel (the paper's
+    compression-inside-the-operator design). JAX tracing fuses the whole
+    plan into one XLA executable; intermediates are vectors, never
     materialized join tables.
   * ``fragment_loop`` — paper-faithful port of the generated C++ (Fig. 3):
     nested ``lax.fori_loop``s walk one fragment at a time, scalar accumulator
@@ -31,6 +32,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable
@@ -52,6 +54,7 @@ from ..storage import (
     PackedColumn,
     build_device_column,
     column_uniques,
+    permute_column,
     resolve_device_encoding,
 )
 from .algebra import ChainPlan, EntityStep, Param, RelHop, SeedIds
@@ -96,12 +99,10 @@ class DeviceIndex:
     # None (e.g. shard-built indexes) disables skipping for this index
     block_src_min: np.ndarray | None = None
     block_src_max: np.ndarray | None = None
-    # per 128-edge row [min, max] of the dst column in CSR order (host
-    # numpy, kernels/active.row_ranges): the source-chunk ranges the hop
-    # kernel walks when this index serves the opposite hop as its
-    # destination-sorted stream — the hop work counters' geometry
-    row_dst_min: np.ndarray | None = None
-    row_dst_max: np.ndarray | None = None
+    # this index's edges as the hop kernel streams them when the index
+    # serves the opposite hop (attach_pull_streams); while None, lowering
+    # binds the CSR arrays, with no row geometry
+    pull: "PullStream | None" = None
 
     @property
     def dst_ids(self) -> jnp.ndarray:
@@ -110,6 +111,52 @@ class DeviceIndex:
     @property
     def measures(self) -> dict[str, jnp.ndarray]:
         return {m: c.materialize() for m, c in self.measure_cols.items()}
+
+
+@dataclass
+class PullStream:
+    """One index's edges in the order the hop kernel reads them when the
+    index serves the opposite hop (``HopOp.pull``), named as that hop sees
+    them: ``dst`` is the index's key per edge, ``src_col`` its other key
+    column, ``measure_cols`` its measures. Each EDGE_BLOCK block holds the
+    same edges as the CSR block, sorted by source inside it
+    (``build_pull_stream``); every column is a permuted copy, in the
+    encoding and width of the CSR column it was permuted from (a dictionary
+    is shared), and each is in the integrity manifest as
+    ``I_<t>.<k>/pull/<column>`` (``__key__`` for ``dst``)."""
+
+    dst: DenseColumn  # int32[E]
+    src_col: DeviceColumn
+    measure_cols: dict[str, DeviceColumn]
+    # per 128-edge row [min, max] of src_col in this order (host numpy,
+    # kernels/active.row_ranges): the source-chunk ranges the hop kernel
+    # walks — the hop work counters' geometry
+    row_src_min: np.ndarray
+    row_src_max: np.ndarray
+
+    def columns(self) -> list[tuple[str, DeviceColumn]]:
+        """``(name, column)`` of every array the kernel streams, under the
+        names of the CSR columns they were permuted from."""
+        return [("__key__", self.dst), ("__dst__", self.src_col),
+                *self.measure_cols.items()]
+
+
+def build_pull_stream(di: DeviceIndex, key_ids, other) -> PullStream:
+    """The pull stream of index ``di``, from its key per edge ``key_ids``
+    and other key column ``other`` (host arrays, CSR order). Sorting each
+    EDGE_BLOCK block by source narrows every 128-edge row's source range to
+    about 1/32 of its block's (the gather loop) and widens its destination
+    range to the destinations it holds (the scatter loop, at most the
+    block's). The answers differ from CSR order's only in the order of
+    accumulation."""
+    key_ids, other = np.asarray(key_ids), np.asarray(other)
+    perm = active_meta.block_source_order(other)
+    return PullStream(
+        DenseColumn(jnp.asarray(key_ids[perm], jnp.int32)),
+        permute_column(di.dst_col, perm),
+        {m: permute_column(c, perm) for m, c in di.measure_cols.items()},
+        *active_meta.row_ranges(other[perm]),
+    )
 
 
 @dataclass
@@ -147,7 +194,6 @@ def build_device_db(
         )
         src = idx.src_ids()
         bmin, bmax = active_meta.block_ranges(src)
-        rmin, rmax = active_meta.row_ranges(cf.values)
         di = DeviceIndex(
             indptr=jnp.asarray(idx.indptr, dtype=jnp.int32),
             src_ids=jnp.asarray(src, dtype=jnp.int32),
@@ -155,8 +201,6 @@ def build_device_db(
             degrees=jnp.asarray(np.diff(idx.indptr), dtype=jnp.int32),
             block_src_min=bmin,
             block_src_max=bmax,
-            row_dst_min=rmin,
-            row_dst_max=rmax,
         )
         for m, cf in idx.columns.items():
             if m == other:
@@ -183,6 +227,59 @@ def build_device_db(
         for a, col in e.attributes.items()
     }
     return DeviceDB(schema, dev, attrs, host_indexes)
+
+
+_PULL_LOCK = threading.Lock()
+
+
+def attach_pull_streams(db: DeviceDB) -> None:
+    """Build the pull stream of every index that has none yet, from its
+    host index: what the single-chip hop kernels read (``GQFastEngine``
+    calls this; the edge-sharded path reads CSR shards only and never
+    does). With an integrity manifest attached, the CSR columns a stream is
+    permuted from must first match their digests, and the stream's copies
+    join the manifest (``storage.integrity.cover_pull_stream``)."""
+    from ..storage.integrity import check_encoded, cover_pull_stream
+
+    covered = getattr(db, "integrity", None) is not None
+    with _PULL_LOCK:
+        for (table, key), di in db.indexes.items():
+            if di.pull is not None:
+                continue
+            if covered:
+                for name, col in [("__dst__", di.dst_col), *di.measure_cols.items()]:
+                    check_encoded(db, table, key, name, col)
+            idx = db.host_indexes[(table, key)]
+            other = db.schema.relationships[table].other_fk(key)
+            stream = build_pull_stream(di, idx.src_ids(), idx.columns[other].values)
+            if covered:
+                cover_pull_stream(db, table, key, stream)
+            di.pull = stream
+
+
+def heal_pull_column(db: DeviceDB, table: str, key: str, name: str) -> None:
+    """Rebuild column ``name`` of index ``(table, key)``'s pull stream in
+    place from the CSR column it was permuted from, which must first match
+    its digest (the scrubber's repair of a ``pull/`` column; it re-verifies
+    the copy before lifting the quarantine)."""
+    from ..storage.integrity import check_encoded
+
+    di = db.indexes[(table, key)]
+    idx = db.host_indexes[(table, key)]
+    other = db.schema.relationships[table].other_fk(key)
+    perm = active_meta.block_source_order(idx.columns[other].values)
+    cols = dict(di.pull.columns())
+    if name == "__key__":
+        cols[name].array = jnp.asarray(idx.src_ids()[perm], jnp.int32)
+        return
+    src = di.dst_col if name == "__dst__" else di.measure_cols[name]
+    check_encoded(db, table, key, name, src)
+    fresh, col = permute_column(src, perm), cols[name]
+    if isinstance(col, DenseColumn):
+        col.array = fresh.array
+    else:
+        col.words = fresh.words
+        col._dense = None
 
 
 def _is_fk(schema: Schema, table: str, attr: str) -> bool:
@@ -591,9 +688,9 @@ class _FrontierInterp(_Interp):
 
     Frontier sparsity (DESIGN.md §Sparsity): every hop first short-circuits an
     all-zero frontier inside the trace (``lax.cond`` on the support count — a
-    died-early chain stops paying per-hop scan cost). Over the
-    destination-sorted edges the kernel then gathers only active frontier
-    chunks but streams every edge block; a hop without a reverse index runs
+    died-early chain stops paying per-hop scan cost). Over the pull stream
+    the kernel then gathers only active frontier chunks but streams every
+    edge block; a hop without a reverse index runs
     over its source-sorted edges, where the per-block src-range metadata
     keeps unreachable blocks from being streamed. ``block_skipping`` ('auto'
     | 'on' | 'off') is threaded through from prepare time."""
@@ -602,7 +699,7 @@ class _FrontierInterp(_Interp):
     # interp) must not branch per-hop: lax.cond with a psum inside one branch
     # deadlocks when shards disagree on the frontier. They opt out here.
     early_exit = True
-    # Hops run the Pallas kernel over the destination-sorted edges; the
+    # Hops run the Pallas kernel over their pull streams; the
     # edge-sharded interp reduces shard-local XLA segments instead.
     kernel_hops = True
     # The edge-sharded interp also opts out of the single-pass fused-region
@@ -677,7 +774,7 @@ class _FrontierInterp(_Interp):
 
     def _kernel_stream(self, op: HopOp) -> str | None:
         """Which stream the hop kernel reads for ``op`` in a batched
-        executable: ``pull`` (destination-sorted), ``src`` (source-sorted),
+        executable: ``pull`` (the pull stream), ``src`` (source-sorted),
         or None when the hop stays on XLA (the ``xla`` rung, a per-row
         measure) — the static twin of ``pull_hop``/``_hop_body``'s routing."""
         if not (self.use_pallas and self.kernel_hops):
@@ -689,7 +786,7 @@ class _FrontierInterp(_Interp):
 
     def _count_hop(self, op: HopOp, w, live, label: str) -> None:
         """The hop's work counters, outside the early-exit cond: rows
-        streamed and, over the destination-sorted stream, the gather loop's
+        streamed and, over the pull stream, the gather loop's
         trips for this frontier's active chunks (kernels/fragment_spmv.py
         ``hop_block``). Source-sorted hops count rows only."""
         from ..kernels import ops as K
@@ -705,7 +802,7 @@ class _FrontierInterp(_Interp):
             self.hops.add(label, w, live, blocks, chunks=n_chunks(w.shape[-1]))
 
     def _count_pull(self, label: str, w, live, pull) -> None:
-        """Counters of a hop over its destination-sorted stream ``pull``,
+        """Counters of a hop over its pull stream ``pull``,
         which streams every edge block."""
         from ..kernels.fragment_spmv import chunk_flags, n_chunks
 
@@ -749,7 +846,7 @@ class _FrontierInterp(_Interp):
         return "dense", None, 0, None
 
     def _pull_operands(self, op: HopOp):
-        """The hop's destination-sorted streams (``op.pull``) as keyword
+        """The hop's pull streams (``op.pull``) as keyword
         arguments of the packed hop entries — packed source ids and a single
         packed measure column decode inside the kernel — or None when the
         hop takes the source-sorted XLA path instead: the ``xla`` rung, no
@@ -777,7 +874,7 @@ class _FrontierInterp(_Interp):
         )
 
     def pull_hop(self, w, op: HopOp, label: str = ""):
-        """The hop kernel over the destination-sorted edges. Skipping there is
+        """The hop kernel over the pull stream. Skipping there is
         by frontier chunk: only 128-entry chunks of ``w`` holding a
         non-identity value are gathered (``block_skipping`` 'off' reads every
         chunk)."""
@@ -892,7 +989,7 @@ class _FrontierInterp(_Interp):
         return cont(out)
 
     def _count_region(self, h1_op, h2_op, hop1, hop2, w, live, labels) -> None:
-        """A fused region's counters: hop1 as a destination-sorted hop over
+        """A fused region's counters: hop1 as a pull-stream hop over
         ``w``; hop2's rows only — its frontier is the intermediate, which
         exists only inside the region (in VMEM, or inside the early-exit
         cond when the region composes the unfused kernels)."""

@@ -125,25 +125,50 @@ def build_index(
 def _pack_words(values: np.ndarray, width: int) -> np.ndarray:
     """Whole-column little-endian bit packing into uint32 words (kernel layout —
     per-column contiguous, not per-fragment padded; offsets are value indices).
-    Up to 32 bits, each value lands in its word and the next one directly:
-    bit fields never overlap, so a weighted bincount (exact in float64 below
-    2^53) ORs them together."""
+    Up to 32 bits, every 32 values fill exactly ``width`` words, at the same
+    bit offsets in each such group: the values are packed one group position
+    at a time, each step ORing that position's value of every group into its
+    word and, where it straddles, the next one."""
     if width <= 32:
-        v = np.asarray(values).astype(np.uint64) & np.uint64((1 << width) - 1)
-        n_words = -(-v.shape[0] * width // 32)
-        pos = np.arange(v.shape[0], dtype=np.uint64) * np.uint64(width)
-        word = (pos >> np.uint64(5)).astype(np.int64)
-        off = pos & np.uint64(31)
-        lo = (v << off) & np.uint64(0xFFFFFFFF)
-        hi = v >> (np.uint64(32) - off)
-        words = np.bincount(word, weights=lo, minlength=n_words)
-        words += np.bincount(word + 1, weights=hi, minlength=n_words + 1)[:n_words]
-        return words.astype(np.uint32)
+        n = np.asarray(values).shape[0]
+        g = -(-n // 32)
+        v = np.zeros(g * 32, np.uint32)
+        v[:n] = np.asarray(values).astype(np.uint32)  # the low 32 bits
+        v &= np.uint32((1 << width) - 1)
+        v = v.reshape(g, 32).T.copy()  # [position, group]
+        out = np.zeros((width, g), np.uint32)
+        for j in range(32):
+            k, off = divmod(j * width, 32)
+            out[k] |= v[j] << np.uint32(off)
+            if off + width > 32:
+                out[k + 1] |= v[j] >> np.uint32(32 - off)
+        return out.T.reshape(-1)[: -(-n * width // 32)]
     buf = C.pack_bits(values, width)
     pad = (-buf.shape[0]) % 4
     if pad:
         buf = np.concatenate([buf, np.zeros(pad, dtype=np.uint8)])
     return buf.view(np.uint32)
+
+
+def _unpack_words(words: np.ndarray, width: int, count: int) -> np.ndarray:
+    """Inverse of :func:`_pack_words` for widths up to 32, on the host: the
+    first ``count`` values as uint32, one group position at a time."""
+    g = -(-count // 32)
+    w = np.zeros(g * width + 1, np.uint32)
+    words = np.asarray(words, np.uint32)[: g * width]
+    w[: words.shape[0]] = words
+    W = np.empty((width + 1, g), np.uint32)  # [word of the group, group]
+    W[:width] = w[: g * width].reshape(g, width).T
+    W[width] = w[width::width][:g]  # the next group's first word
+    mask = np.uint32((1 << width) - 1)
+    v = np.empty((32, g), np.uint32)
+    for j in range(32):
+        k, off = divmod(j * width, 32)
+        x = W[k] >> np.uint32(off)
+        if off + width > 32:
+            x |= W[k + 1] << np.uint32(32 - off)
+        v[j] = x & mask
+    return v.T.reshape(-1)[:count]
 
 
 def _encoded_size(values: np.ndarray, indptr: np.ndarray, domain: int, enc: str) -> int:

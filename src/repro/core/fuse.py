@@ -28,7 +28,7 @@ Region formation rules (DESIGN.md §Pipelined fusion):
 Whether a formed region runs fused is decided at dispatch
 (``kernels/ops.py``): ``fusion='auto'`` runs it unfused when the resident
 ``(8, n_mid)`` intermediate would exceed ``FUSED_VMEM_BUDGET_BYTES``.
-Both phases stream every edge block of their destination-sorted edges;
+Both phases stream every edge block of their pull streams;
 skipping is by active frontier chunk, as in the unfused kernel.
 """
 from __future__ import annotations

@@ -213,7 +213,7 @@ class HopOp:
     # None when the index was built without it → hop always full-scans
     block_src_min: Any = None
     block_src_max: Any = None
-    # the same edges sorted by destination, for the hop kernel (PullBinding)
+    # the same edges keyed by destination, for the hop kernel (PullBinding)
     pull: "PullBinding | None" = None
 
     @property
@@ -224,16 +224,18 @@ class HopOp:
 @dataclass(eq=False)
 class PullBinding:
     """The hop's edges as stored by the index keyed on its *destination*
-    (``I_{table.dst_key}``): destinations sorted (that index's expanded row
-    ids), sources in its dst column (any DeviceColumn kind), and the measure
-    rebound to that index's columns. The hop kernel streams this order, so
-    every 128-edge row scatters into one or two destination chunks."""
+    (``I_{table.dst_key}``), in that index's pull-stream order
+    (``executor.PullStream``): blocks of EDGE_BLOCK edges in destination
+    order, each sorted by source inside (CSR order, with no row ranges,
+    where the index has no stream yet). Destinations are that index's row
+    ids, sources its dst column (any DeviceColumn kind), and the measure is
+    rebound to its columns."""
 
-    dst_ids: Any  # int32[E], sorted
+    dst_ids: Any  # int32[E]
     src_col: Any  # repro.storage.DeviceColumn
     measure: LExpr | None = None
-    # per 128-edge row [src_min, src_max] of src_col (the reverse index's
-    # DeviceIndex.row_dst_*; host numpy) — the hop work counters' geometry
+    # per 128-edge row [src_min, src_max] of src_col (PullStream.row_src_*;
+    # host numpy) — the hop work counters' geometry
     row_src_min: Any = None
     row_src_max: Any = None
     _coverage: dict = field(default_factory=dict, repr=False)
@@ -465,20 +467,25 @@ def _pull_binding(db, table: str, src_key: str, measure) -> PullBinding | None:
     if rev is None:
         return None
 
+    stream = rev.pull
+    cols = stream.measure_cols if stream is not None else rev.measure_cols
+
     def rebind(e):
         if isinstance(e, LCol) and e.key[0] == "edge":
             attr = e.key[3]
-            return LCol(("edge", table, dst_key, attr), rev.measure_cols[attr])
+            return LCol(("edge", table, dst_key, attr), cols[attr])
         if isinstance(e, LBin):
             return LBin(e.op, rebind(e.left), rebind(e.right))
         if isinstance(e, LCall):
             return LCall(e.fn, tuple(rebind(a) for a in e.args))
         return e
 
+    measure = rebind(measure) if measure is not None else None
+    if stream is None:
+        return PullBinding(rev.src_ids, rev.dst_col, measure)
     return PullBinding(
-        rev.src_ids, rev.dst_col, rebind(measure) if measure is not None else None,
-        row_src_min=getattr(rev, "row_dst_min", None),
-        row_src_max=getattr(rev, "row_dst_max", None),
+        stream.dst.materialize(), stream.src_col, measure,
+        row_src_min=stream.row_src_min, row_src_max=stream.row_src_max,
     )
 
 

@@ -4,8 +4,8 @@ GQ-Fast's selective-query win (paper §4-5) comes from touching only the index
 *fragments* reachable from the active sources. The hop kernel
 (:mod:`.fragment_spmv`) streams ``EDGE_BLOCK``-edge blocks; for source-sorted
 edges this module restores fragment-level selectivity at block granularity
-(the served path, whose edges are destination-sorted, skips by frontier
-chunk instead — see :mod:`.ops`):
+(the served path, whose edge blocks follow the destination, skips by
+frontier chunk instead — see :mod:`.ops`):
 
   * :func:`block_ranges` — build-time (host, numpy): for each EDGE_BLOCK-sized
     block of the CSR-ordered edge arrays, its ``[src_min, src_max]`` source-id
@@ -26,7 +26,9 @@ chunk instead — see :mod:`.ops`):
 
 The same module holds the geometry behind the hop kernel's work counters
 (:func:`row_ranges`, :func:`chunk_coverage`): per 128-edge row of a stream,
-the range of source chunks its inner gather loop walks.
+the range of source chunks its inner gather loop walks — and the pull
+stream's within-block edge order that narrows those ranges
+(:func:`block_source_order`).
 
 Skipping is *bit-identical* to the full scan for every combine op: a skipped
 block's sources all carry the ⊕-identity, so its per-block contribution is the
@@ -143,6 +145,29 @@ def row_ranges(src_ids) -> tuple[np.ndarray, np.ndarray]:
     starts = np.arange(0, src.shape[0], LANES)
     return (np.minimum.reduceat(src, starts).astype(np.int32),
             np.maximum.reduceat(src, starts).astype(np.int32))
+
+
+def block_source_order(src_ids) -> np.ndarray:
+    """int64[E]: the permutation that sorts each EDGE_BLOCK block of an edge
+    stream by source and leaves the blocks where they are (host/numpy, one
+    row-wise sort of the ``[blocks, EDGE_BLOCK]`` source keys, in the
+    narrowest unsigned type that holds them). The sort is stable, so equal
+    sources keep their stream order; in the CSR order of an index keyed on
+    the hop's destination that breaks ties by destination. The last block's
+    padding keys sort after every source and are cut off."""
+    src = np.asarray(src_ids)
+    E = src.shape[0]
+    if E == 0:
+        return np.zeros(0, np.int64)
+    nb = n_edge_blocks(E)
+    top = int(src.max())
+    dtype = next(t for t in (np.uint16, np.uint32, np.uint64)
+                 if top < np.iinfo(t).max)
+    keys = np.full(nb * EDGE_BLOCK, np.iinfo(dtype).max, dtype)
+    keys[:E] = src
+    order = np.argsort(keys.reshape(nb, EDGE_BLOCK), axis=1, kind="stable")
+    order += (np.arange(nb, dtype=np.int64) * EDGE_BLOCK)[:, None]
+    return order.reshape(-1)[:E]
 
 
 def chunk_coverage(row_min, row_max, E: int, n_src: int) -> np.ndarray:

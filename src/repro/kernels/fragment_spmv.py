@@ -29,10 +29,15 @@ Layout (what the TPU compiler sees):
     the scatter.
 
 Per-edge cost therefore follows the width of a row's source and destination
-ranges, not the domain sizes. Edges sorted by destination (the index keyed
-on the hop's destination — the executor's pull layout) give each row one or
-two destination chunks; edges sorted by source (CSR) give narrow source
-ranges and, with a sparse frontier, few non-identity rows.
+ranges, not the domain sizes. The executor's pull stream holds the edges of
+the index keyed on the hop's destination, block by block. In that index's
+CSR order a row scatters into one or two destination chunks but gathers
+from sources spread over the source domain; with each block sorted by
+source, a row gathers from about 1/32 of its block's source range and
+scatters into the chunks of the destinations it holds; the executor
+streams that order (``core/executor.build_pull_stream``). Edges sorted by source across blocks (the CSR order of the hop's own index)
+give narrow source ranges and, with a sparse frontier, few non-identity
+rows.
 
 Exactness: the gather is a select, so it is exact. The sum scatter splits
 each f32 product into three bf16 terms whose one-hot products are exact in

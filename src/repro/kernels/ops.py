@@ -12,8 +12,8 @@ batch of one. Two skipping mechanisms, both bit-identical to a full scan
 
   * **frontier chunks** (always on unless ``block_skipping='off'``): the
     kernel gathers only from 128-entry frontier chunks that hold a
-    non-identity value — the served path's skipping, since its edges are
-    destination-sorted;
+    non-identity value — the served path's skipping, since its edges come
+    in blocks of the index keyed on the destination (the pull stream);
   * **edge blocks** (``blocks=(src_min, src_max)`` of source-sorted edges,
     kernels/active.py): the scalar-prefetched block list drives the edge
     streams' ``index_map`` so unreachable blocks are never DMA'd. Two tiers:
@@ -281,7 +281,7 @@ def fragment_spmv_packed(weights, src_ids, dst, measure=None, mdict=None, *,
 @dataclasses.dataclass(eq=False)
 class FusedHopOperands:
     """One hop's streams for the fused entries, in any edge order (the
-    executor passes destination-sorted ones). The frontier is *not* here:
+    executor passes pull streams). The frontier is *not* here:
     hop1 reads the caller's ``weights``, hop2 reads the VMEM scratch."""
 
     src_ids: Any

@@ -21,9 +21,13 @@ immediately **quarantined** (every read raises
 :class:`~repro.robust.errors.IntegrityError` — wrong answers become typed
 errors), then **healed** from the latest checksummed snapshot
 (``storage/snapshot.py``) by swapping in the snapshot's verified arrays, and
-**re-verified** before the quarantine lifts. A column that cannot be healed
-(no snapshot configured, or the snapshot read itself fails) stays
-quarantined — detected-and-contained beats silent corruption.
+**re-verified** before the quarantine lifts. A pull-stream copy
+(``I_<t>.<k>/pull/<col>``, the permuted edges the hop kernel reads) heals
+without a snapshot: it is permuted anew from its CSR column, once that
+column matches its own digest (``core.executor.heal_pull_column``). A column
+that cannot be healed (no snapshot configured, the snapshot read itself
+fails, or a copy's CSR column is corrupt too) stays quarantined —
+detected-and-contained beats silent corruption.
 
 Fault site ``scrub.verify``: ``raise``/``delay`` fire per scrubbed column;
 ``corrupt`` transforms the scrubber's *read* of the encoded bytes (the
@@ -147,22 +151,27 @@ class Scrubber:
 
     def _heal(self, addr: str, tk: tuple[str, str], name: str, col,
               dig: dict[str, Any]) -> bool:
-        """Quarantine → reload encoded arrays from the snapshot → re-verify →
+        """Quarantine → reload encoded arrays from the snapshot (a pull
+        copy: permute it anew from its verified CSR column) → re-verify →
         lift quarantine. Snapshot reads here deliberately bypass the
         ``snapshot.load`` fault site (``load_column_arrays``): the heal path
         must not be re-corrupted by a chaos spec aimed at full restores."""
         import jax.numpy as jnp
 
         from ..storage.columns import DenseColumn, DictPackedColumn
-        from ..storage.integrity import crc32c, crc32c_parts, decode_fresh
         from ..storage.snapshot import latest_generation, load_column_arrays
 
         t, k = tk
         col._quarantined = True
-        if self.snapshot_dir is None:
+        if self.snapshot_dir is None and not name.startswith("pull/"):
             self._count("scrub_failures")
             return False
         try:
+            if name.startswith("pull/"):
+                from ..core.executor import heal_pull_column
+
+                heal_pull_column(self.db.device, t, k, name[len("pull/"):])
+                return self._reverify(addr, t, k, name, col, dig)
             gen = self.generation
             if gen is None:
                 gen = latest_generation(self.snapshot_dir)
@@ -180,22 +189,29 @@ class Scrubber:
                         arrays["dict"], dtype=col.dictionary.dtype
                     )
                 col._dense = None
-            for _ in range(REPAIR_RETRIES):
-                if (crc32c_parts(_read_encoded(col)) == int(dig["encoded_crc"])
-                        and crc32c(decode_fresh(col)) == int(dig["decoded_crc"])):
-                    col._quarantined = False
-                    self._count("scrub_repairs")
-                    if self.on_heal is not None:
-                        self.on_heal(addr)
-                    return True
-            raise IntegrityError(
-                f"column {addr} still fails verification after snapshot heal",
-                table=t, key=k, column=name,
-                expected_crc=int(dig["encoded_crc"]),
-            )
+            return self._reverify(addr, t, k, name, col, dig)
         except Exception:  # noqa: BLE001 — a failed heal must not kill the loop
             self._count("scrub_failures")
             return False  # stays quarantined: contained, not silent
+
+    def _reverify(self, addr: str, t: str, k: str, name: str, col,
+                  dig: dict[str, Any]) -> bool:
+        """Re-verify a repaired column and lift its quarantine, or raise."""
+        from ..storage.integrity import crc32c, crc32c_parts, decode_fresh
+
+        for _ in range(REPAIR_RETRIES):
+            if (crc32c_parts(_read_encoded(col)) == int(dig["encoded_crc"])
+                    and crc32c(decode_fresh(col)) == int(dig["decoded_crc"])):
+                col._quarantined = False
+                self._count("scrub_repairs")
+                if self.on_heal is not None:
+                    self.on_heal(addr)
+                return True
+        raise IntegrityError(
+            f"column {addr} still fails verification after heal",
+            table=t, key=k, column=name,
+            expected_crc=int(dig["encoded_crc"]),
+        )
 
     # ------------------------------------------------------------------
     def tick(self) -> dict[str, int]:

@@ -23,6 +23,7 @@ from .policy import (  # noqa: F401
     choose_device_encoding,
     column_uniques,
     device_space_report,
+    permute_column,
     resolve_device_encoding,
 )
 from .snapshot import (  # noqa: F401

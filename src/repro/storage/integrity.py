@@ -21,7 +21,9 @@ every device-resident column a verifiable identity:
     ``encoded_crc`` a few columns per tick.
 
 Digest addresses are strings (JSON-manifest friendly): ``I_DT.doc/__dst__``
-for the hop's destination column, ``I_DT.doc/<measure>`` for measures.
+for the hop's destination column, ``I_DT.doc/<measure>`` for measures, and
+``I_DT.doc/pull/<column>`` for the permuted copies of the index's pull
+stream (``pull/__key__`` for its key per edge), which the hop kernel reads.
 """
 from __future__ import annotations
 
@@ -134,10 +136,17 @@ def column_digest(col: DeviceColumn) -> dict[str, Any]:
 
 def iter_columns(device_db) -> list[tuple[str, tuple[str, str], str, DeviceColumn]]:
     """Every device column as ``(addr, (table, key), column_name, col)``;
-    ``addr`` is the manifest key ``I_<t>.<k>/<col>``."""
+    ``addr`` is the manifest key ``I_<t>.<k>/<col>``. An index's pull
+    stream, once built (``core.executor.attach_pull_streams``), adds its
+    permuted copies as ``I_<t>.<k>/pull/<col>``, with ``pull/__key__`` for
+    the index's key per edge."""
     out = []
-    for (t, k), di in device_db.indexes.items():
-        for name, col in [("__dst__", di.dst_col), *di.measure_cols.items()]:
+    for (t, k), di in list(device_db.indexes.items()):
+        cols = [("__dst__", di.dst_col), *di.measure_cols.items()]
+        p = getattr(di, "pull", None)
+        if p is not None:
+            cols += [(f"pull/{name}", col) for name, col in p.columns()]
+        for name, col in cols:
             out.append((f"I_{t}.{k}/{name}", (t, k), name, col))
     return out
 
@@ -147,6 +156,12 @@ def build_manifest(device_db) -> dict[str, dict[str, Any]]:
     verified) DB. This is the host-side source of truth the verified-read
     path and the scrubber check against."""
     return {addr: column_digest(col) for addr, _, _, col in iter_columns(device_db)}
+
+
+def _install(col: DeviceColumn, tk: tuple[str, str], name: str,
+             dig: dict[str, Any], verify_reads: bool) -> None:
+    col._addr = (*tk, name)
+    col._expected_crc = int(dig["decoded_crc"]) if verify_reads else None
 
 
 def attach_manifest(device_db, manifest: dict[str, dict[str, Any]] | None = None,
@@ -161,13 +176,56 @@ def attach_manifest(device_db, manifest: dict[str, dict[str, Any]] | None = None
     if manifest is None:
         manifest = build_manifest(device_db)
     device_db.integrity = manifest
-    for addr, (t, k), name, col in iter_columns(device_db):
+    device_db.integrity_verify_reads = verify_reads
+    for addr, tk, name, col in iter_columns(device_db):
         dig = manifest.get(addr)
-        if dig is None:
-            continue
-        col._addr = (t, k, name)
-        col._expected_crc = int(dig["decoded_crc"]) if verify_reads else None
+        if dig is not None:
+            _install(col, tk, name, dig, verify_reads)
     return manifest
+
+
+def check_encoded(device_db, table: str, key: str, name: str,
+                  col: DeviceColumn) -> None:
+    """Raise :class:`repro.robust.errors.IntegrityError` unless ``col``'s
+    stored bytes match the manifest's ``encoded_crc`` for
+    ``I_<table>.<key>/<name>`` — the check on a CSR column before a
+    pull-stream copy is permuted from it."""
+    dig = device_db.integrity.get(f"I_{table}.{key}/{name}")
+    actual = crc32c_parts(encoded_parts(col))
+    if dig is not None and actual == int(dig["encoded_crc"]):
+        return
+    from ..robust.errors import IntegrityError
+
+    raise IntegrityError(
+        f"column I_{table}.{key}/{name} does not match its digest; no copy "
+        "is derived from it",
+        table=table, key=key, column=name, actual_crc=actual,
+        expected_crc=None if dig is None else int(dig["encoded_crc"]),
+    )
+
+
+def cover_pull_stream(device_db, table: str, key: str, stream) -> None:
+    """Add ``stream``, the pull stream of index ``(table, key)`` just
+    permuted from its verified CSR columns, to the attached manifest and
+    install its digests. A digest the manifest already holds for a copy (a
+    snapshot's) must match the rebuilt bytes."""
+    from ..robust.errors import IntegrityError
+
+    manifest = device_db.integrity
+    verify_reads = getattr(device_db, "integrity_verify_reads", True)
+    for name, col in stream.columns():
+        addr, name = f"I_{table}.{key}/pull/{name}", f"pull/{name}"
+        dig = column_digest(col)
+        old = manifest.get(addr)
+        if old is not None and int(old["encoded_crc"]) != dig["encoded_crc"]:
+            raise IntegrityError(
+                f"rebuilt column {addr} does not match its recorded digest",
+                table=table, key=key, column=name,
+                expected_crc=int(old["encoded_crc"]),
+                actual_crc=dig["encoded_crc"],
+            )
+        manifest[addr] = dig
+        _install(col, (table, key), name, dig, verify_reads)
 
 
 def detach_manifest(device_db) -> None:

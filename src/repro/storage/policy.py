@@ -157,6 +157,24 @@ def build_device_column(cf, enc: str, out_dtype, uniques=None) -> DeviceColumn:
     raise ValueError(f"unknown device encoding {enc!r}")
 
 
+def permute_column(col: DeviceColumn, perm: np.ndarray) -> DeviceColumn:
+    """``col`` with its values in the order ``perm`` (host int array), in the
+    same encoding and width: dense arrays are permuted, packed index words
+    are unpacked on the host, permuted and re-packed, and a dictionary is
+    shared with ``col``."""
+    from ..core.fragments import _pack_words, _unpack_words
+
+    if isinstance(col, DenseColumn):
+        return DenseColumn(jnp.asarray(np.asarray(col.array)[perm]))
+    if not isinstance(col, (PackedColumn, DictPackedColumn)):
+        raise TypeError(f"not a device column: {type(col).__name__}")
+    idx = _unpack_words(np.asarray(col.words), col.width, col.count)[perm]
+    words = jnp.asarray(_pack_words(idx, col.width))
+    if isinstance(col, DictPackedColumn):
+        return DictPackedColumn(words, col.width, col.count, col.dictionary)
+    return PackedColumn(words, col.width, col.count, col.out_dtype)
+
+
 def device_space_report(device_db) -> dict[str, Any]:
     """Real device bytes, per index per column — what HBM actually holds, as
     opposed to the host byte-array accounting of ``FragmentIndex.total_bytes``.
@@ -165,7 +183,10 @@ def device_space_report(device_db) -> dict[str, Any]:
     ``materialized_bytes`` counts decoded fallback copies currently pinned by
     the ``materialize()`` memo (fragment_loop / distributed prepares): those
     columns occupy packed *plus* dense bytes until the database is dropped, so
-    the compression ratio only holds while ``materialized_bytes`` is 0."""
+    the compression ratio only holds while ``materialized_bytes`` is 0.
+    ``pull_bytes`` is an index's pull stream, once built (its permuted
+    copies of the key ids and columns; a dictionary is shared), counted in
+    its ``device_bytes``."""
     rep: dict[str, Any] = {
         "indexes": {}, "total_bytes": 0, "dense_bytes": 0, "materialized_bytes": 0,
     }
@@ -187,10 +208,17 @@ def device_space_report(device_db) -> dict[str, Any]:
             total += b
             dense_total += db_
             mat_total += col.materialized_nbytes
-        rep["indexes"][f"I_{t}.{k}"] = {
-            "columns": cols, "struct_bytes": struct,
-            "device_bytes": total, "dense_bytes": dense_total,
-        }
+        entry = {"columns": cols, "struct_bytes": struct}
+        p = getattr(di, "pull", None)
+        if p is not None:
+            own = [c for _, c in p.columns()]
+            entry["pull_bytes"] = sum(
+                c.device_nbytes - arr_bytes(getattr(c, "dictionary", None))
+                for c in own)
+            total += entry["pull_bytes"]
+            dense_total += sum(4 * c.count for c in own)
+        entry.update(device_bytes=total, dense_bytes=dense_total)
+        rep["indexes"][f"I_{t}.{k}"] = entry
         rep["total_bytes"] += total
         rep["dense_bytes"] += dense_total
         rep["materialized_bytes"] += mat_total

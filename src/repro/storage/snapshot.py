@@ -34,8 +34,10 @@ Layout::
 Logical array names: ``host/<t>.<k>/indptr``, ``host/<t>.<k>/<col>/values``
 (+``/packed``), ``dev/<t>.<k>/<col>/{array|words|dict}``,
 ``dev/<t>.<k>/block_src_{min,max}``, ``attr/<entity>/<name>``. Derivable
-arrays (CSR ``src_ids``, ``degrees``) are rebuilt from ``indptr`` on restore
-rather than stored. Relationship-table rows are reconstructed from the
+arrays (CSR ``src_ids``, ``degrees``) are rebuilt on restore rather than
+stored, and each index's pull stream by the engine that serves the restored
+database (``core.executor.attach_pull_streams``), against the digests the
+snapshot recorded for it. Relationship-table rows are reconstructed from the
 fk1-direction index, so restored raw tables are in (fk1, fk2)-sorted order —
 relationally identical to the originals (aggregation is order-independent),
 not byte-identical row order.

@@ -68,7 +68,14 @@ def _first_pull_hop(pq) -> HopOp:
     for op in X.iter_flat_ops(pq.phys):
         if isinstance(op, HopOp) and op.pull is not None:
             return op
-    raise AssertionError("no destination-sorted hop")
+    raise AssertionError("no pull-stream hop")
+
+
+def _pull_hop(pq, table: str, src_key: str) -> HopOp:
+    for op in X.iter_flat_ops(pq.phys):
+        if isinstance(op, HopOp) and (op.table, op.src_key) == (table, src_key):
+            return op
+    raise AssertionError(f"no hop {table}.{src_key}")
 
 
 def _frontier(kind: str, n_src: int, B: int, rng) -> np.ndarray:
@@ -120,6 +127,94 @@ def test_hop_counters_match_kernel_loop_recount(engine, kind, B, skip):
     plain, none = _hop(op, w, counting=False, skip=skip)
     assert none.slots == () and np.asarray(none.values).shape == (0,)
     assert np.array_equal(out, plain)  # counting never changes the answer
+
+
+@pytest.mark.parametrize("kind,B", [("sparse", 8), ("dense", 8), ("padded", 5)])
+@pytest.mark.parametrize("table,dst_key", [("DT", "Term"), ("DA", "Author")])
+def test_source_ordered_counters_match_kernel_loop_recount(engine, table, dst_key,
+                                                           kind, B):
+    """AS's Doc→Term and Doc→Author hops read source-ordered pull streams:
+    the gathers counted from their row ranges are the trips of the kernel's
+    loop over the permuted rows — fewer than over the same blocks in CSR
+    order."""
+    op = _pull_hop(engine.prepare(QUERY_AS), table, "Doc")
+    stream = engine.db.device.index(table, dst_key).pull
+    assert op.pull.src_col is stream.src_col
+    w = _frontier(kind, engine.db.schema.domain_size("Document"), B,
+                  np.random.default_rng(11))
+    _, counts = _hop(op, w, counting=True)
+    (h,) = counts.decode()
+    rows, gathers = recount(w, np.asarray(op.pull.src_col.materialize()))
+    assert (h["rows"], h["gathers"]) == (rows, gathers)
+    csr_rows, csr_gathers = recount(
+        w, np.asarray(engine.db.device.index(table, dst_key).dst_ids))
+    assert rows == csr_rows and gathers < csr_gathers
+
+
+@pytest.fixture(scope="module")
+def dense_db(db):
+    dense = GQFastDatabase(db.schema, account_space=False, device_encodings="dense")
+    X.attach_pull_streams(dense.device)
+    return dense
+
+
+def test_pull_stream_blocks_hold_the_csr_edges_sorted_by_source(dense_db):
+    """Each EDGE_BLOCK block of a pull stream holds the CSR block's edges
+    sorted by (source, destination); in the kernel's layout the last
+    block's padding stays at its end with source ``n_src``."""
+    from repro.kernels.fragment_spmv import HopCfg, edge_operands
+
+    for (table, key), di in dense_db.device.indexes.items():
+        p = di.pull
+        src, dst = np.asarray(p.src_col.materialize()), np.asarray(p.dst.array)
+        csr_src, csr_dst = np.asarray(di.dst_ids), np.asarray(di.src_ids)
+        E = src.shape[0]
+        cols = [(np.asarray(p.measure_cols[m].materialize()),
+                 np.asarray(c.materialize())) for m, c in di.measure_cols.items()]
+        for b in range(0, E, EDGE_BLOCK):
+            sl = slice(b, b + EDGE_BLOCK)
+            got = zip(src[sl].tolist(), dst[sl].tolist(), *(g[sl].tolist() for g, _ in cols))
+            want = zip(csr_src[sl].tolist(), csr_dst[sl].tolist(),
+                       *(c[sl].tolist() for _, c in cols))
+            assert sorted(got) == sorted(want)
+            assert (np.lexsort((dst[sl], src[sl])) == np.arange(src[sl].shape[0])).all()
+        assert np.array_equal(p.row_src_min, A.row_ranges(src)[0])
+        rel = dense_db.schema.relationships[table]
+        n_src = dense_db.schema.domain_size(rel.fk_entity(rel.other_fk(key)))
+        ops, nb = edge_operands(HopCfg(n_src, 1), E, src, dst, None, None)
+        last = np.asarray(ops[0]).reshape(nb, EDGE_BLOCK)[-1]
+        tail = E - (nb - 1) * EDGE_BLOCK
+        assert np.array_equal(last[:tail], src[(nb - 1) * EDGE_BLOCK:])
+        assert (last[tail:] == n_src).all()
+
+
+def test_pull_order_of_source_sorted_blocks_is_the_csr_order():
+    """Where every block of an index is sorted by source as it stands, the
+    stable sort keeps the CSR order exactly, so the stream costs no loop
+    trip more than the CSR edges, and the answers match the reference."""
+    from repro.core.reference import run_sql
+    from repro.core.schema import EntityTable, RelationshipTable, Schema
+
+    doc = np.repeat(np.arange(2048), 4)
+    term = np.arange(doc.shape[0]) // 8
+    schema = Schema(
+        entities={"Document": EntityTable("Document", 2048),
+                  "Term": EntityTable("Term", int(term.max()) + 1)},
+        relationships={"DT": RelationshipTable(
+            "DT", "Doc", "Term", "Document", "Term",
+            {"Doc": doc, "Term": term, "Fre": 1 + term % 5})},
+    )
+    eng = GQFastEngine(GQFastDatabase(schema, account_space=False))
+    di = eng.db.device.index("DT", "Doc")
+    src, dst = np.asarray(di.dst_ids), np.asarray(di.src_ids)
+    assert np.array_equal(A.block_source_order(src), np.arange(src.shape[0]))
+    assert np.array_equal(np.asarray(di.pull.src_col.materialize()), src)
+    assert np.array_equal(np.asarray(di.pull.dst.array), dst)
+    q = "SELECT dt.Doc, SUM(dt.Fre) FROM DT dt WHERE dt.Term = :t GROUP BY dt.Doc"
+    pq = eng.prepare(q)
+    assert pq.hop_routes() == [("DT.Term->Document", "pallas")]
+    for t in (0, 77):
+        assert np.array_equal(pq(t=t), run_sql(schema, q, {"t": t}))
 
 
 def test_fused_region_counts_hop1_gathers_and_hop2_rows(engine):

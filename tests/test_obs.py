@@ -385,20 +385,37 @@ def test_runner_spans_reach_a_profiler_trace(small_db, tmp_path):
 
 
 def test_disabled_span_path_allocates_nothing():
-    """No tracer, no profiler session: a span costs no memory at all."""
-    import tracemalloc
+    """No tracer, no profiler session: a span costs no memory at all.
+    Measured in a fresh process: tracemalloc counts every thread's
+    allocations, and a test runner's own threads (a worker's message
+    channel) allocate whenever a message arrives."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
 
-    assert not T.tracing()
-    for _ in range(10):  # warm any lazily created state
-        with T.span("dispatch"):
-            pass
-    tracemalloc.start()
-    base = tracemalloc.get_traced_memory()[0]
-    for _ in range(10_000):
-        with T.span("dispatch"):
-            pass
-    grown, peak = (m - base for m in tracemalloc.get_traced_memory())
-    tracemalloc.stop()
+    code = textwrap.dedent("""
+        import tracemalloc
+        from repro.obs import trace as T
+
+        assert not T.tracing()
+        for _ in range(10):  # warm any lazily created state
+            with T.span("dispatch"):
+                pass
+        tracemalloc.start()
+        base = tracemalloc.get_traced_memory()[0]
+        for _ in range(10_000):
+            with T.span("dispatch"):
+                pass
+        grown, peak = (m - base for m in tracemalloc.get_traced_memory())
+        print(grown, peak)
+    """)
+    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+    env = {**os.environ, "PYTHONPATH": src}
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    grown, peak = map(int, out.stdout.split()[-2:])
     # 10,000 Spans would take megabytes, one live Span hundreds of bytes
     assert grown < 1024 and peak < 1024, (grown, peak)
 

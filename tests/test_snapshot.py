@@ -432,6 +432,100 @@ def test_corrupt_scrub_heal_end_to_end(schema, tmp_path):
         assert np.array_equal(np.asarray(eng.prepare(sql)(t=9)), ref)
 
 
+def _corrupt_any(col):
+    """Flip one bit of a column's stored bytes, whatever its kind."""
+    if hasattr(col, "words"):
+        _corrupt_in_place(col)
+    else:
+        bad = np.asarray(col.array).copy()
+        bad[bad.shape[0] // 2] ^= 1 << 20
+        col.array = jnp.asarray(bad)
+
+
+@pytest.mark.parametrize("name", ["__key__", "__dst__", "Fre"])
+def test_scrub_heals_a_corrupt_pull_copy_from_its_csr_column(schema, name):
+    """The hop kernel reads each index's pull stream, permuted copies of its
+    CSR columns: a flip in a copy is detected, quarantined and healed by
+    permuting the verified CSR column anew (no snapshot needed), and the
+    answers afterwards are bit-identical."""
+    db = _db(schema, "auto")
+    eng = GQFastEngine(db)
+    refs = {sql: np.asarray(eng.prepare(sql)(t=9)) for sql in (SQL, SQL_SUM)}
+    man = attach_manifest(db.device)
+    assert f"I_DT.Doc/pull/{name}" in man
+    col = dict(db.device.indexes[("DT", "Doc")].pull.columns())[name]
+    _corrupt_any(col)
+    reg = MetricsRegistry()
+    healed: list[str] = []
+    s = Scrubber(db, snapshot_dir=None, registry=reg, on_heal=healed.append)
+    stats = s.scrub_full()
+    assert stats["healed"] == 1 and stats["failed"] == 0
+    assert healed == [f"I_DT.Doc/pull/{name}"] and not col._quarantined
+    eng.invalidate_prepared()
+    for sql, ref in refs.items():
+        assert np.array_equal(np.asarray(eng.prepare(sql)(t=9)), ref)
+
+
+def test_pull_copy_of_a_corrupt_csr_column_stays_quarantined(schema):
+    """A copy is never re-derived from a CSR column that fails its own
+    digest: with both corrupt and no snapshot, both stay quarantined and a
+    read of the copy raises IntegrityError instead of serving bad bytes."""
+    db = _db(schema, "dense")
+    GQFastEngine(db)
+    attach_manifest(db.device)
+    di = db.device.indexes[("DT", "Doc")]
+    _corrupt_any(di.dst_col)
+    _corrupt_any(di.pull.src_col)
+    s = Scrubber(db, snapshot_dir=None, registry=MetricsRegistry())
+    assert s.scrub_full()["failed"] == 2
+    assert di.dst_col._quarantined and di.pull.src_col._quarantined
+    with pytest.raises(IntegrityError):
+        di.pull.src_col.materialize()
+
+
+def test_pull_streams_built_under_a_manifest_join_it(schema):
+    """An engine built on a database that already carries a manifest (a
+    restored one) checks the CSR columns before permuting them, and its
+    streams join the manifest with verified reads; a flipped copy then
+    fails the prepare that would read it."""
+    db = _db(schema, "dense")
+    attach_manifest(db.device)
+    GQFastEngine(db)
+    assert db.device.integrity == build_manifest(db.device)
+    key = db.device.indexes[("DT", "Doc")].pull.dst
+    assert key._addr == ("DT", "Doc", "pull/__key__")
+    _corrupt_any(key)
+    with pytest.raises(IntegrityError):
+        GQFastEngine(db).prepare(SQL)
+
+    bad = _db(schema, "dense")
+    attach_manifest(bad.device)
+    _corrupt_any(bad.device.indexes[("DT", "Term")].dst_col)
+    with pytest.raises(IntegrityError) as ei:
+        GQFastEngine(bad)
+    assert ei.value.context["column"] == "__dst__"
+
+
+def test_rebuilt_pull_copy_must_match_a_recorded_digest(schema, tmp_path):
+    """A restored database carries the digests its snapshot recorded for the
+    pull copies; an engine whose rebuilt copy differs raises IntegrityError
+    and leaves the index without a stream rather than with an unchecked
+    one."""
+    db = _db(schema, "packed")
+    GQFastEngine(db)
+    snapshot_db(db, str(tmp_path))
+    back = restore_db(str(tmp_path))
+    assert all(di.pull is None for di in back.device.indexes.values())
+    GQFastEngine(back)  # the rebuilt copies match the recorded digests
+    assert back.device.integrity == build_manifest(back.device)
+
+    again = restore_db(str(tmp_path))
+    again.device.integrity["I_DT.Term/pull/__dst__"]["encoded_crc"] ^= 1
+    with pytest.raises(IntegrityError):
+        GQFastEngine(again)
+    assert again.device.indexes[("DT", "Term")].pull is None
+
+
 def test_load_column_arrays_verified(schema, tmp_path):
     db = _db(schema, "packed")
     gen_path = snapshot_db(db, str(tmp_path))
